@@ -50,6 +50,7 @@ def _bench(quick: bool, out_path: str) -> dict:
     from repro.data.synthetic import make_batch_fn
     from repro.distributed import compression
     from repro.distributed import sharding as shard_lib
+    from repro.launch.mesh import auto_mesh
     from repro.models.model import build_model
     from repro.train import sharded
     from repro.utils import hlo_analysis
@@ -58,8 +59,8 @@ def _bench(quick: bool, out_path: str) -> dict:
     model = build_model(cfg)
     shape = ShapeConfig("bench", 64, 32, "train")
     batch_fn = make_batch_fn(cfg, shape)
-    mesh8 = jax.make_mesh((N_DEV,), ("data",))
-    mesh1 = jax.make_mesh((1,), ("data",))
+    mesh8 = auto_mesh((N_DEV,), ("data",))
+    mesh1 = auto_mesh((1,), ("data",))
 
     def mkopt(bucketed: bool, mesh) -> CollageAdamW:
         bp = BucketPolicy(
@@ -110,7 +111,7 @@ def _bench(quick: bool, out_path: str) -> dict:
         # each ship ONE compressed all-reduce; embed and head lower with a
         # single JOINT (pipe × dp) replica group instead of one dp group
         # per stage row (train/sharded.py dedup)
-        pmesh = jax.make_mesh((2, 4), ("pipe", "data"))
+        pmesh = auto_mesh((2, 4), ("pipe", "data"))
         opt = mkopt(False, pmesh)
         state = sharded.init_state(model, opt, jax.random.PRNGKey(0),
                                    pmesh, axis="data",

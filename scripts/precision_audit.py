@@ -37,6 +37,7 @@ import jax  # noqa: E402
 from repro.analysis import audit_cell, is_sixteen_bit  # noqa: E402
 from repro.analysis.source_lint import lint_paths  # noqa: E402
 from repro.launch import dryrun  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 
 # one small dense, one mid dense (GQA), one MoE — the shapes that exercise
 # every param-layout branch (flat buckets, tree/pipeline, expert tensors)
@@ -59,8 +60,8 @@ D_OVERRIDES = dict(engine="sharded", bucketed="0", smoke="1")
 
 def _mesh(mode: str):
     if mode.startswith("pipeline"):
-        return jax.make_mesh((2, 4), ("pipe", "data"))
-    return jax.make_mesh((8,), ("data",))
+        return auto_mesh((2, 4), ("pipe", "data"))
+    return auto_mesh((8,), ("data",))
 
 
 def run_one(arch: str, strategy: str, mode: str, overrides: dict) -> dict:

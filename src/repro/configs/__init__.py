@@ -1,4 +1,6 @@
 """Architecture registry: ``--arch <id>`` resolution for every entrypoint."""
+import dataclasses
+
 from repro.configs import (codeqwen1_5_7b, gemma3_27b, gpt, granite_3_2b,
                            internlm2_1_8b, internvl2_1b,
                            jamba_1_5_large_398b, moonshot_v1_16b_a3b,
@@ -35,5 +37,20 @@ def get_config(arch: str, smoke: bool = False):
     return mod.SMOKE if smoke else mod.CONFIG
 
 
-__all__ = ["ARCHS", "ASSIGNED", "SHAPES", "get_config", "ModelConfig",
-           "RunConfig", "ShapeConfig", "Group", "Sub"]
+def with_layers(cfg: ModelConfig, n_layers: int | None) -> ModelConfig:
+    """Cut the decoder to ``n_layers`` (None keeps the config's depth) —
+    the one-chip depth cut that keeps every published width. The count
+    must be a whole number of layer periods (jamba's attention period,
+    gemma's local:global period; 1 for a dense stack)."""
+    if n_layers is None:
+        return cfg
+    period = cfg.attn_every if cfg.family == "hybrid" \
+        else (cfg.local_global_period or 1)
+    if n_layers <= 0 or n_layers % period:
+        raise ValueError(f"--layers {n_layers}: {cfg.name} needs a positive "
+                         f"multiple of its {period}-layer period")
+    return dataclasses.replace(cfg, n_layers=n_layers)
+
+
+__all__ = ["ARCHS", "ASSIGNED", "SHAPES", "get_config", "with_layers",
+           "ModelConfig", "RunConfig", "ShapeConfig", "Group", "Sub"]

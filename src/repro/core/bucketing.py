@@ -39,6 +39,9 @@ import jax.numpy as jnp
 LANES = 128      # TPU VPU lane count — minimum bucket padding granularity
 SUBLANES = 8     # (8, 128) native VMEM tile: default pad keeps rows aligned
 PAD_DEFAULT = SUBLANES * LANES
+# one full (256, 128) block of the fused collage_update kernel: buckets (and
+# ZeRO shards) padded to a multiple of it tile the kernel with full blocks
+BLOCK_PAD = 256 * LANES
 
 # Bucket-resident role arrays (leaf names under BucketedParams/-OptState).
 # grad_err rows are 2-D (n_dp, padded): per-DEVICE compressor state of the

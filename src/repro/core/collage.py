@@ -16,8 +16,9 @@ Numerical placement follows the paper exactly:
   * weight decay fused into the summed update (Alg. 2 line 12) by default.
 
 A fused single-HBM-pass Pallas kernel implementing the same math lives in
-``repro.kernels.collage_update`` (enable with ``use_fused_kernel=True``);
-its oracle is this module. Two execution layouts exist:
+``repro.kernels.collage_update``; the bucket layout runs it on a TPU
+(``use_fused_kernel=True`` forces it elsewhere, interpreted); its oracle
+is this module. Two execution layouts exist:
 
   * tree layout (``init``/``step``): per-leaf pytree state — the reference
     semantics. With ``use_fused_kernel`` the step routes through the bucket
@@ -94,7 +95,6 @@ class CollageAdamW:
                  policy: PrecisionPolicy | None = None,
                  compute_metrics: bool = False,
                  use_fused_kernel: bool = False,
-                 kernel_interpret: bool = True,
                  sr_seed: int = 0):
         self.lr = learning_rate if callable(learning_rate) else (lambda t: jnp.float32(learning_rate))
         self.b1 = float(b1)
@@ -103,8 +103,10 @@ class CollageAdamW:
         self.wd = float(weight_decay)
         self.policy = policy or PrecisionPolicy()
         self.compute_metrics = compute_metrics
+        # tree layout: route through the fused kernel (re-flattening shim).
+        # The bucketed layout always takes the kernel on a TPU; elsewhere
+        # this flag asks for the interpreted kernel over the jnp oracle.
         self.use_fused_kernel = use_fused_kernel
-        self.kernel_interpret = kernel_interpret
         # SR rounding-noise seed. Configurable so a migrated/resumed run does
         # not silently replay the identical noise stream (the old behaviour
         # hard-coded PRNGKey(0) in both init and convert_state).
@@ -202,8 +204,7 @@ class CollageAdamW:
             # per-leaf threefry stream below, equally unbiased).
             from repro.kernels.collage_update import ops as kops
             new_params, new_state, metrics = kops.fused_step(
-                self, grads, params, state, lr, bc1, bc2,
-                interpret=self.kernel_interpret)
+                self, grads, params, state, lr, bc1, bc2)
             return new_params, new_state, metrics
 
         leaves_g, treedef = jax.tree_util.tree_flatten(grads)

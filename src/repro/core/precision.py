@@ -64,13 +64,15 @@ class BucketPolicy:
     per-launch VMEM working set and gives the scheduler parallelism; None
     means one bucket per dtype.
     ``pad_multiple``: flat-axis padding granularity; must be a multiple of
-    128 (VPU lanes). Shard-aware callers pass lcm(128, dp_size) so buckets
-    divide the FSDP axis exactly (distributed.sharding.bucket_pad_multiple).
+    128 (VPU lanes). The default is one full (256, 128) fused-kernel block
+    (``bucketing.BLOCK_PAD``). Shard-aware callers pass
+    distributed.sharding.bucket_pad_multiple so buckets divide the FSDP
+    axis exactly.
     """
 
     enabled: bool = False
     max_bucket_elems: int | None = None
-    pad_multiple: int = 1024     # 8 sublanes × 128 lanes
+    pad_multiple: int = 256 * 128     # bucketing.BLOCK_PAD
 
 
 @dataclasses.dataclass(frozen=True)

@@ -523,10 +523,7 @@ def pipeline_apply(body_fn: Callable, staged_params, x_micro, *,
         return stage_schedule(body_fn, params_local, xs_local,
                               axis=axis, n_stages=S)
 
-    from jax.experimental.shard_map import shard_map
-    spec_p = jax.tree_util.tree_map(lambda _: P(axis), staged_params)
-    del spec_p
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(P(axis), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(P(axis), P()), out_specs=P(),
+                       check_vma=False)
     return fn(staged_params, x_micro)

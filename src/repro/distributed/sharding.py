@@ -146,8 +146,9 @@ def bucket_spec(leaf, mesh: Mesh, fsdp: bool = True) -> P:
 
 
 def bucket_pad_multiple(mesh: Mesh, block: int = 1) -> int:
-    """Layout pad_multiple that keeps every bucket dividing both the VMEM
-    tile (8×128) and the mesh's dp axes — pass to BucketPolicy.
+    """Layout pad_multiple that keeps every bucket dividing the mesh's dp
+    axes with each device's share a whole number of fused-kernel blocks
+    (``bucketing.BLOCK_PAD``) — pass to BucketPolicy.
 
     ``block``: quantization block size of the compressed gradient collective
     (compression.BLOCK for fp8) — each device's ZeRO flat-axis shard must
@@ -159,7 +160,7 @@ def bucket_pad_multiple(mesh: Mesh, block: int = 1) -> int:
     for a in (dp if isinstance(dp, tuple) else (dp,)):
         if a:
             n *= sizes[a]
-    return math.lcm(bucketing.PAD_DEFAULT, n * block)
+    return n * math.lcm(bucketing.BLOCK_PAD, block)
 
 
 def _is_grad_err_leaf(path) -> bool:

@@ -33,6 +33,7 @@ from repro.core import bucketing
 from repro.core.collage import CollageOptState, StepMetrics, bucket_state
 from repro.core.mcf import Expansion
 from repro.core.precision import Strategy
+from repro.kernels import on_tpu
 from repro.kernels.collage_update import collage_update as cu
 from repro.kernels.collage_update import ref as cu_ref
 
@@ -52,9 +53,10 @@ _FIELD_ROLE = {"m": "m", "vhi": "vhi", "vlo": "vlo", "delta": "delta",
 
 
 def _update_one_bucket(opt, state_dict, g, lr, bc1, bc2, seed,
-                       interpret: bool, elem_offset=None):
-    """Fused update of one flat bucket: Pallas kernel or the bit-identical
-    pure-jnp oracle (same math, same metrics partial tiling).
+                       elem_offset=None):
+    """Fused update of one flat bucket: the Pallas kernel on a TPU (or when
+    ``opt.use_fused_kernel`` asks for it elsewhere, interpreted), else the
+    bit-identical pure-jnp oracle (same math).
 
     ``elem_offset`` (SR): element-0's position inside the FULL bucket — a
     ZeRO shard passes its flat-axis start so the counter-based noise is
@@ -63,10 +65,9 @@ def _update_one_bucket(opt, state_dict, g, lr, bc1, bc2, seed,
     kw = dict(b1=opt.b1, b2=opt.b2, eps=opt.eps, wd=opt.wd, strategy=code,
               pt_decay=(opt.policy.wd_mode == "pytorch"),
               compute_metrics=opt.compute_metrics)
-    if opt.use_fused_kernel:
+    if opt.use_fused_kernel or on_tpu():
         return cu.collage_bucket_update(state_dict, g, lr, bc1, bc2, seed,
-                                        elem_offset, interpret=interpret,
-                                        **kw)
+                                        elem_offset, **kw)
     # flat library-semantics path (one fused XLA computation per bucket);
     # fast metrics sums — equal to the kernel's tiled partials up to f32
     # summation order (the tiled oracle mode is for bit-identity tests).
@@ -173,8 +174,7 @@ def bucketed_step(opt, grads, bparams: bucketing.BucketedParams,
         off = elem_offsets[i] if elem_offsets is not None else None
         g_i = gdata[i] if reduce_fn is None else reduce_fn(i, gdata[i])
         out, part = _update_one_bucket(opt, sd, g_i, lr, bc1, bc2,
-                                       seed, opt.kernel_interpret,
-                                       elem_offset=off)
+                                       seed, elem_offset=off)
         for f in fields:
             new[f].append(out[f])
         if part is not None:
@@ -200,8 +200,7 @@ def bucketed_step(opt, grads, bparams: bucketing.BucketedParams,
 # tree-compat shim (CollageAdamW.step with use_fused_kernel=True)
 # --------------------------------------------------------------------------
 
-def fused_step(opt, grads, params, state: CollageOptState, lr, bc1, bc2,
-               interpret: bool = True):
+def fused_step(opt, grads, params, state: CollageOptState, lr, bc1, bc2):
     """Drop-in replacement for CollageAdamW.step — all six strategies.
 
     Re-flattens the pytrees every call (the cost ``bucketed_step`` removes);
@@ -233,7 +232,7 @@ def fused_step(opt, grads, params, state: CollageOptState, lr, bc1, bc2,
         seed = bucketing.fold_seed(seed_base, t, i) \
             if seed_base is not None else None
         out, part = _update_one_bucket(opt, sd, g_buckets[i],
-                                       lr, bc1, bc2, seed, interpret)
+                                       lr, bc1, bc2, seed)
         for f in fields:
             new[f].append(out[f])
         if part is not None:

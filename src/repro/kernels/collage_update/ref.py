@@ -3,9 +3,11 @@ non-fused per-leaf update from repro.core.collage applied to flat bucket
 arrays — the kernel must be bit-identical to the library semantics, for all
 six strategies AND the StepMetrics partials.
 
-Metrics partials are computed with the same (block_rows, 128) tiling the
-kernel uses (``choose_block_rows`` is shared) so the f32 partial-sum order —
-and therefore every bit of the reduction — matches the in-kernel epilogue.
+Metrics partials are computed with the same (block_rows, 128) tiling and
+the same fold/sum helpers the kernel uses (``choose_block_rows``,
+``fold_rows``, ``sum_partial_tiles`` are shared) so the f32 partial-sum
+order — and therefore every bit of the reduction — matches the in-kernel
+epilogue.
 The stochastic-rounding noise stream is the shared counter-based definition
 in ``repro.core.bucketing`` (bit-identical by construction).
 """
@@ -19,7 +21,8 @@ import jax.numpy as jnp
 from repro.core import bucketing, mcf
 from repro.core.mcf import Expansion
 from repro.kernels.collage_update.collage_update import (
-    BLOCK_ROWS, LANES, choose_block_rows, state_fields)
+    BLOCK_ROWS, LANES, choose_block_rows, fold_rows, state_fields,
+    sum_partial_tiles)
 
 
 def collage_bucket_update_ref(state: dict, g, lr, bc1, bc2, seed=None,
@@ -31,7 +34,7 @@ def collage_bucket_update_ref(state: dict, g, lr, bc1, bc2, seed=None,
     """Oracle for ``collage_bucket_update``: same signature/returns.
 
     ``tiled_metrics=True`` (oracle mode) mirrors the kernel's per-tile
-    det_sum partials bit-for-bit; ``False`` computes the same partials with
+    partials bit-for-bit; ``False`` computes the same partials with
     ordinary fused ``jnp.sum`` — O(1) ops for production-size buckets, equal
     to the tiled result up to f32 summation order. ``elem_offset`` shifts
     the SR noise index the same way the kernel's scalar does (ZeRO shards
@@ -134,27 +137,22 @@ def _metric_partials_fast(upd, eff, g32):
 
 
 def _metric_partials(upd, eff, g32, block_rows):
-    """Tiled partial sums matching the in-kernel epilogue bit-for-bit: one
-    (5,) row per grid step, summed across the grid in grid order."""
+    """Tiled partial sums matching the in-kernel epilogue bit-for-bit: each
+    (block_rows, 128) tile folded to (8, 128) by ``fold_rows`` (vectorized
+    over tiles here), then the same wrapper-side ``sum_partial_tiles``."""
     n = upd.shape[0]
     rows = n // LANES
     br = choose_block_rows(rows, block_rows)
     grid = rows // br
 
-    def tiles(x):
-        return x.reshape(grid, br, LANES)
+    def tiles(x):   # (grid, br, 128) → fold over axis 1 → (grid, 8, 128)
+        x = x.reshape(grid, br, LANES).transpose(1, 0, 2)
+        return fold_rows(x).transpose(1, 0, 2)
 
-    u3, e3, g3 = tiles(upd), tiles(eff), tiles(g32)
-    det = bucketing.det_sum
-    rows_out = []
-    for i in range(grid):
-        u, e, gg = u3[i], e3[i], g3[i]
-        rows_out.append((
-            det(u * e), det(u * u), det(e * e),
-            det(((jnp.abs(u) > 0) & (e == 0)).astype(jnp.float32)),
-            det(gg * gg)))
-    return tuple(det(jnp.stack([r[k] for r in rows_out]))
-                 for k in range(5))
+    lost = ((jnp.abs(upd) > 0) & (eff == 0)).astype(jnp.float32)
+    parts = [tiles(q) for q in (upd * eff, upd * upd, eff * eff, lost,
+                                g32 * g32)]
+    return sum_partial_tiles(jnp.stack(parts, axis=1))
 
 
 # jitted oracle: un-jitted (eager) execution skips XLA's fusion-context

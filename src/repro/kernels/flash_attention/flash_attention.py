@@ -27,9 +27,16 @@ the kernels — an O(L·dh) elementwise pass, not a materialized score.
 ``flash_mha`` wraps forward+backward in a ``jax.custom_vjp``: causal,
 sliding-window and GQA, arbitrary (odd) L via zero-padding to the block
 multiple with an in-kernel valid-length mask. ``interpret=None`` resolves
-to interpret-mode off TPU, so the same entry point runs tier-1 CI on CPU
-and compiles to Mosaic on device. Oracle = ``ref.attention_ref`` (full
-masked softmax), forward AND ``jax.grad`` swept in tests/test_flash_vjp.py.
+to interpret-mode off TPU (``repro.kernels.resolve_interpret``), so the
+same entry point runs tier-1 CI on CPU and compiles to Mosaic on device.
+Oracle = ``ref.attention_ref`` (full masked softmax), forward AND
+``jax.grad`` swept in tests/test_flash_vjp.py.
+
+Row statistics (LSE, D) cross the kernel boundary as (B, H, L, 128) f32,
+the row value replicated across the 128 lanes: a per-row block is then a
+tile-legal (blk, 128) slab, and ``_col`` reads its first lane as the
+(blk, 1) column that broadcasts over a score tile. Outside the kernels
+they are kept as compact (B, H, L) — only the kernel operands are wide.
 """
 from __future__ import annotations
 
@@ -40,8 +47,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 F32 = jnp.float32
+STAT_LANES = 128   # lane width of the replicated LSE / D kernel operands
+
+
+def _wide(x):
+    """(B, H, L) row statistic → (B, H, L, 128) kernel operand."""
+    return jnp.broadcast_to(x[..., None], x.shape + (STAT_LANES,))
+
+
+def _col(ref_block):
+    """(blk, 128) replicated statistic block → (blk, 1) column."""
+    return ref_block[:, 0:1]
 
 
 def _band_lo_block(qi, blk_q: int, blk_k: int, window: int):
@@ -74,8 +94,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, blk_q: int,
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(F32)                          # (blk_q, dh)
     nk = seq_len // blk_k
-    m = jnp.full((blk_q,), NEG_INF, F32)
-    l = jnp.zeros((blk_q,), F32)
+    m = jnp.full((blk_q, 1), NEG_INF, F32)               # row max (column)
+    l = jnp.zeros((blk_q, 1), F32)                       # row sum (column)
     acc = jnp.zeros((blk_q, q.shape[-1]), F32)
 
     def body(kj, carry):
@@ -86,25 +106,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, blk_q: int,
         bad = _mask(s.shape, qi * blk_q, kj * blk_k, causal=causal,
                     window=window, valid_len=valid_len)
         s = jnp.where(bad, NEG_INF, s)
-        m_new = jnp.maximum(m, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=1)
-        acc_new = acc * corr[:, None] + p @ v
+        l_new = l * corr + p.sum(axis=1, keepdims=True)
+        acc_new = acc * corr + p @ v
         return m_new, l_new, acc_new
 
     # causal: skip key blocks strictly after this query block
     n_iter = pl.cdiv((qi + 1) * blk_q, blk_k) if causal else nk
     lo = _band_lo_block(qi, blk_q, blk_k, window) if window else 0
     m, l, acc = jax.lax.fori_loop(lo, n_iter, body, (m, l, acc))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     # fully-masked (padded) rows: m never left NEG_INF (l is NOT a valid
     # detector — every masked tile contributes p = exp(NEG_INF − NEG_INF)
     # = 1 to it). Park their LSE at +big so the backward recomputation
     # exp(NEG_INF − lse) is exactly 0 instead of exp(0) = 1.
-    lse_ref[0, 0] = jnp.where(m > NEG_INF * 0.5,
-                              m + jnp.log(jnp.maximum(l, 1e-30)),
-                              jnp.float32(-NEG_INF))
+    lse = jnp.where(m > NEG_INF * 0.5, m + jnp.log(jnp.maximum(l, 1e-30)),
+                    jnp.float32(-NEG_INF))
+    lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
 def _fwd_call(q, k, v, *, causal, window, blk_q, blk_k, valid_len,
@@ -115,7 +135,7 @@ def _fwd_call(q, k, v, *, causal, window, blk_q, blk_k, valid_len,
     kernel = functools.partial(_fwd_kernel, blk_q=blk_q, blk_k=blk_k,
                                seq_len=L, causal=causal, window=window,
                                scale=scale, valid_len=valid_len)
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid=(B, H, L // blk_q),
         in_specs=[
@@ -126,12 +146,15 @@ def _fwd_call(q, k, v, *, causal, window, blk_q, blk_k, valid_len,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, blk_q, dh), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, 1, blk_q, STAT_LANES),
+                         lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B, H, L), F32)],
+                   jax.ShapeDtypeStruct((B, H, L, STAT_LANES), F32)],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
+    return o, lse[..., 0]
 
 
 # -------------------------------------------------------------- backward --
@@ -141,8 +164,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(F32)                          # (blk_q, dh)
     do = do_ref[0, 0].astype(F32)
-    lse = lse_ref[0, 0]                                  # (blk_q,)
-    delta = delta_ref[0, 0]
+    lse = _col(lse_ref[0, 0])                            # (blk_q, 1)
+    delta = _col(delta_ref[0, 0])
 
     def body(kj, acc):
         k = k_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(F32)
@@ -151,9 +174,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
         bad = _mask(s.shape, qi * blk_q, kj * blk_k, causal=causal,
                     window=window, valid_len=valid_len)
         s = jnp.where(bad, NEG_INF, s)
-        p = jnp.exp(s - lse[:, None])                    # masked → exactly 0
+        p = jnp.exp(s - lse)                             # masked → exactly 0
         dp = do @ v.T                                    # (blk_q, blk_k)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         return acc + ds @ k
 
     n_iter = pl.cdiv((qi + 1) * blk_q, blk_k) if causal \
@@ -178,16 +201,16 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk, dv = carry
         q = q_ref[0, 0, pl.ds(qi * blk_q, blk_q), :].astype(F32)
         do = do_ref[0, 0, pl.ds(qi * blk_q, blk_q), :].astype(F32)
-        lse = lse_ref[0, 0, pl.ds(qi * blk_q, blk_q)]
-        delta = delta_ref[0, 0, pl.ds(qi * blk_q, blk_q)]
+        lse = _col(lse_ref[0, 0, pl.ds(qi * blk_q, blk_q), :])
+        delta = _col(delta_ref[0, 0, pl.ds(qi * blk_q, blk_q), :])
         s = (q @ k.T) * scale                            # (blk_q, blk_k)
         bad = _mask(s.shape, qi * blk_q, kj * blk_k, causal=causal,
                     window=window, valid_len=valid_len)
         s = jnp.where(bad, NEG_INF, s)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dv = dv + p.T @ do
         dp = do @ v.T
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dk = dk + ds.T @ q
         return dk, dv
 
@@ -221,7 +244,8 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, window, blk_q, blk_k,
     group = H // Hkv
     scale = dh ** -0.5
     # D-trick: one O(L·dh) elementwise pass, fused by XLA — never a score
-    delta = (do.astype(F32) * o.astype(F32)).sum(-1)     # (B, H, L)
+    delta = _wide((do.astype(F32) * o.astype(F32)).sum(-1))
+    lse = _wide(lse)
     kw = dict(blk_q=blk_q, blk_k=blk_k, seq_len=L, causal=causal,
               window=window, scale=scale, valid_len=valid_len)
 
@@ -233,12 +257,15 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, window, blk_q, blk_k,
             pl.BlockSpec((1, 1, L, dh), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, L, dh), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, blk_q, dh), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda b, h, i: (b, h, i)),
-            pl.BlockSpec((1, 1, blk_q), lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, 1, blk_q, STAT_LANES),
+                         lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, blk_q, STAT_LANES),
+                         lambda b, h, i: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, blk_q, dh), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, F32),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -250,8 +277,10 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, window, blk_q, blk_k,
                          lambda b, hk, j, g: (b, hk * group + g, 0, 0)),
             pl.BlockSpec((1, 1, L, dh),
                          lambda b, hk, j, g: (b, hk * group + g, 0, 0)),
-            pl.BlockSpec((1, 1, L), lambda b, hk, j, g: (b, hk * group + g, 0)),
-            pl.BlockSpec((1, 1, L), lambda b, hk, j, g: (b, hk * group + g, 0)),
+            pl.BlockSpec((1, 1, L, STAT_LANES),
+                         lambda b, hk, j, g: (b, hk * group + g, 0, 0)),
+            pl.BlockSpec((1, 1, L, STAT_LANES),
+                         lambda b, hk, j, g: (b, hk * group + g, 0, 0)),
             pl.BlockSpec((1, 1, blk_k, dh), lambda b, hk, j, g: (b, hk, j, 0)),
             pl.BlockSpec((1, 1, blk_k, dh), lambda b, hk, j, g: (b, hk, j, 0)),
         ],
@@ -262,6 +291,7 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, window, blk_q, blk_k,
         out_shape=[jax.ShapeDtypeStruct(k.shape, F32),
                    jax.ShapeDtypeStruct(v.shape, F32)],
         interpret=interpret,
+        name="flash_dkv",
     )(q, do, lse, delta, k, v)
     return dq, dk, dv
 
@@ -313,12 +343,6 @@ def _mha_bwd(causal, window, blk_q, blk_k, interpret, res, do):
 _flash_mha.defvjp(_mha_fwd, _mha_bwd)
 
 
-def default_interpret() -> bool:
-    """Interpret-mode everywhere but real TPU — the same entry point runs
-    tier-1 CI on CPU and compiles to Mosaic on device."""
-    return jax.default_backend() != "tpu"
-
-
 def flash_mha(q, k, v, *, causal=True, window=0, blk_q=128, blk_k=128,
               interpret=None):
     """Differentiable flash attention (the training/prefill entry point).
@@ -334,16 +358,14 @@ def flash_mha(q, k, v, *, causal=True, window=0, blk_q=128, blk_k=128,
     Hkv = k.shape[1]
     assert H % Hkv == 0, (H, Hkv)
     assert k.shape == v.shape == (B, Hkv, L, dh), (q.shape, k.shape, v.shape)
-    if interpret is None:
-        interpret = default_interpret()
     return _flash_mha(q, k, v, bool(causal), int(window), int(blk_q),
-                      int(blk_k), bool(interpret))
+                      int(blk_k), resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "blk_q", "blk_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, blk_q=128, blk_k=128,
-                    interpret=True):
+                    interpret=None):
     """Forward-only convenience wrapper (serving path ≥8k). Same kernel as
     ``flash_mha`` — kept as a jitted entry point for direct callers."""
     return flash_mha(q, k, v, causal=causal, window=window, blk_q=blk_q,

@@ -4,19 +4,29 @@ module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``. Since JAX 0.9 the default
+    is ``Explicit`` axes, which the shard_map engine and the GSPMD paths
+    (``.at[]`` gathers, un-meshed jit) do not use. Every mesh the program
+    builds goes through here."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_mesh(dp: int, tp: int, pods: int = 1):
     """Arbitrary small meshes (tests / examples)."""
     if pods > 1:
-        return jax.make_mesh((pods, dp, tp), ("pod", "data", "model"))
-    return jax.make_mesh((dp, tp), ("data", "model"))
+        return auto_mesh((pods, dp, tp), ("pod", "data", "model"))
+    return auto_mesh((dp, tp), ("data", "model"))
 
 
 # TPU v5e-like hardware model for the roofline (§Roofline constants).

@@ -54,8 +54,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import get_config, with_layers
 from repro.data.synthetic import SyntheticCorpus
+from repro.launch import compile_cache
 from repro.launch.api import (AdmissionError, CapabilityError, PoolError,
                               Request, RequestResult, SamplingParams,
                               ServeError, make_engine)
@@ -879,10 +880,14 @@ def main(argv=None):
                          "Pallas flash kernels when prompt_len >= this "
                          "(0 = off, unset = config default) — long-prompt "
                          "prefill without the O(L^2) score buffer")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the decoder to this many layers (a whole "
+                         "number of layer periods); widths stay published")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.configure()
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+    cfg = with_layers(get_config(args.arch, smoke=args.smoke), args.layers)
     if args.flash_min_len is not None:
         cfg = dataclasses.replace(cfg, flash_min_len=args.flash_min_len)
     model = build_model(cfg)

@@ -15,19 +15,24 @@ exported BEFORE launch (jax locks the device count at first use).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
+import tempfile
 import time
+from typing import Any
 
 import jax
 
-from repro.configs import get_config
+from repro.configs import get_config, with_layers
 from repro.configs.base import ShapeConfig
 from repro.core.collage import CollageAdamW, cosine_schedule
 from repro.core.precision import BucketPolicy, PrecisionPolicy, parse_strategy
 from repro.data.synthetic import make_batch_fn
 from repro.distributed import compression
 from repro.distributed import sharding as shard_lib
+from repro.launch import compile_cache
+from repro.launch import mesh as mesh_lib
 from repro.models.model import build_model
 from repro.train import checkpoint as ckpt_lib
 from repro.train import sharded
@@ -36,17 +41,17 @@ from repro.train.elastic import RunSupervisor, SupervisorConfig
 
 
 def build(args):
-    cfg = get_config(args.arch, smoke=args.smoke)
+    cfg = with_layers(get_config(args.arch, smoke=args.smoke), args.layers)
     shape = ShapeConfig("custom", args.seq_len, args.batch, "train")
     model = build_model(cfg)
     mesh = None
     pipeline_axis = "pipe" if args.pipeline_stages > 1 else None
     if args.dp > 1 or pipeline_axis:
         if pipeline_axis:
-            mesh = jax.make_mesh((args.pipeline_stages, args.dp),
-                                 ("pipe", "data"))
+            mesh = mesh_lib.auto_mesh((args.pipeline_stages, args.dp),
+                                      ("pipe", "data"))
         else:
-            mesh = jax.make_mesh((args.dp,), ("data",))
+            mesh = mesh_lib.auto_mesh((args.dp,), ("data",))
     pad = shard_lib.bucket_pad_multiple(mesh, block=compression.BLOCK) if mesh is not None \
         else None
     bucket_policy = BucketPolicy(enabled=args.bucketed) if pad is None else \
@@ -71,15 +76,29 @@ def build(args):
             virtual_stages=args.virtual_stages if pipeline_axis else 1,
             flash_min_len=args.flash_min_len)
     else:
+        # the old state is dead once the step returns: donating it lets
+        # the new params/optimizer state reuse its buffers in place
         step_fn = jax.jit(train_loop.make_train_step(
             model, opt, microbatch=args.microbatch, remat=args.remat,
             grad_compression=args.grad_compression,
-            flash_min_len=args.flash_min_len))
+            flash_min_len=args.flash_min_len), donate_argnums=(0,))
     batch_fn = make_batch_fn(cfg, shape, seed=args.seed)
     return cfg, model, opt, step_fn, batch_fn, mesh, pipeline_axis
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainRun:
+    """What ``main`` returns: the logged metrics plus the host-clock facts
+    a caller (``chip_smoke.py``) reports — nothing here is a device
+    metric."""
+
+    history: list        # logged metric dicts, one per logged step
+    compile_s: float     # ahead-of-time compile of the step
+    step_s: list         # per-step seconds, after block_until_ready
+    compiled: Any        # the compiled step (HLO text, memory analysis)
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt-tiny")
     ap.add_argument("--precision", default="C")
@@ -134,17 +153,25 @@ def main(argv=None):
                          "score buffer in either pass)")
     ap.add_argument("--no-metrics", action="store_true")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the decoder to this many layers (a whole "
+                         "number of layer periods); widths stay published")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
+    ap.add_argument("--ckpt-dir", default=os.path.join(
+        tempfile.gettempdir(), "repro_train_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default=None)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None) -> TrainRun:
+    args = parse_args(argv)
 
     if args.xla_latency_hiding:
         # must land in XLA_FLAGS before the first backend init (imports
-        # don't trigger it; jax.make_mesh below does). The flags are
+        # don't trigger it; building the mesh below does). The flags are
         # registered on every backend but only move the schedule on GPU —
         # SNIPPETS latency-hiding recipe.
         lh = ("--xla_gpu_enable_latency_hiding_scheduler=true "
@@ -155,6 +182,7 @@ def main(argv=None):
             print("[xla-latency-hiding] CPU backend: flags parsed but "
                   "scheduling is unchanged (informational)")
 
+    compile_cache.configure()
     cfg, model, opt, step_fn, batch_fn, mesh, pipeline_axis = build(args)
     if mesh is not None:
         vstages = args.virtual_stages if pipeline_axis else 1
@@ -193,12 +221,21 @@ def main(argv=None):
             start = extra["step"]
             print(f"resumed from step {start}")
 
+    # compile once, ahead of time: the step's compile time is set-up, and
+    # the executable (HLO, memory analysis) is what callers inspect
+    t0 = time.perf_counter()
+    compiled = step_fn.lower(state, batch_fn(start)).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"compiled train step in {compile_s:.1f}s")
+
     sup = RunSupervisor(SupervisorConfig(args.ckpt_dir, args.ckpt_every))
-    history = []
+    history, step_s = [], []
     t0 = time.time()
 
     def logged_step(state, batch):
-        state, metrics = step_fn(state, batch)
+        ts = time.perf_counter()
+        state, metrics = jax.block_until_ready(compiled(state, batch))
+        step_s.append(time.perf_counter() - ts)
         step = int(state.opt_state.step)
         if step % args.log_every == 0 or step == 1:
             m = {k: float(v) for k, v in metrics.items()}
@@ -211,12 +248,11 @@ def main(argv=None):
     state, step, _ = sup.run(state, logged_step, batch_fn, args.steps,
                              start_step=start)
     dt = time.time() - t0
-    tok = args.batch * args.seq_len * (step - start)
-    print(f"done: {step} steps, {dt:.1f}s, {tok / max(dt, 1e-9):.0f} tok/s")
+    print(f"done: {step} steps, {dt:.1f}s host wall")
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:
             json.dump(history, f)
-    return history
+    return TrainRun(history, compile_s, step_s, compiled)
 
 
 if __name__ == "__main__":

@@ -25,13 +25,25 @@ import dataclasses
 import time
 from typing import Callable, Optional
 
+import jax
+
 from repro.train import checkpoint as ckpt_lib
+
+# Runtime statuses no checkpoint restore can cure: the device is out of
+# memory, or XLA/Mosaic refused the program. Restoring and retrying would
+# fail the same way forever, so they propagate.
+_FATAL_STATUS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT", "UNIMPLEMENTED")
+
+
+def _fatal(e: BaseException) -> bool:
+    return isinstance(e, jax.errors.JaxRuntimeError) \
+        and str(e).lstrip().startswith(_FATAL_STATUS)
 
 
 @dataclasses.dataclass
 class SupervisorConfig:
     ckpt_dir: str
-    ckpt_every: int = 100
+    ckpt_every: int = 100     # ≤ 0: never checkpoint (nothing to restore)
     keep_last: int = 3
     deadline_slack: float = 3.0
     min_step_time: float = 1e-3
@@ -41,7 +53,9 @@ class RunSupervisor:
     """Drives train steps with checkpointing + failure recovery.
 
     ``fault_hook(step)`` (tests) may raise to simulate a host crash; the
-    supervisor restores and continues, and records every recovery.
+    supervisor restores and continues, and records every recovery. Device
+    out-of-memory and compile refusals (``_FATAL_STATUS``) are not crashes
+    a restore can cure: they propagate.
 
     ``recoveries`` records the FAULTING step of every incident (crash or
     straggler) — not the checkpoint step it rolled back to, which is what
@@ -83,6 +97,8 @@ class RunSupervisor:
                 batch = batch_fn(step)
                 state, last_metrics = train_step(state, batch)
             except (RuntimeError, TimeoutError) as e:  # real crash
+                if _fatal(e):
+                    raise
                 restore_step = ckpt_lib.latest_step(self.cfg.ckpt_dir)
                 if restore_step is None:
                     raise RuntimeError("fault before first checkpoint") from e
@@ -110,7 +126,8 @@ class RunSupervisor:
             else:
                 self.step_times.append(dt)
             step += 1
-            if step % self.cfg.ckpt_every == 0 or step == n_steps:
+            if self.cfg.ckpt_every > 0 and (step % self.cfg.ckpt_every == 0
+                                            or step == n_steps):
                 ckpt_lib.save(self.cfg.ckpt_dir, step, state,
                               keep_last=self.cfg.keep_last,
                               extra={"step": step})

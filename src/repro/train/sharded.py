@@ -82,7 +82,6 @@ from typing import Any, Callable, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import bucketing
@@ -708,8 +707,8 @@ def make_sharded_train_step(model: Model, opt: CollageAdamW, mesh: Mesh, *,
                               virtual_stages=virtual_stages)
         bspecs = batch_pspecs(batch, axis=axis)
         mspecs = {k: P() for k in _METRIC_KEYS}
-        fn = shard_map(body, mesh=mesh, in_specs=(sspecs, bspecs),
-                       out_specs=(sspecs, mspecs), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(sspecs, bspecs),
+                           out_specs=(sspecs, mspecs), check_vma=False)
         return fn(state, batch)
 
     if jit:

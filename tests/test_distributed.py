@@ -51,7 +51,8 @@ def test_pjit_train_step_matches_single_device():
         sref, mref = jax.jit(step)(state0, batch_fn(0))
 
         # pjit on (data=2, model=4)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ("data", "model"))
         state_abs = jax.eval_shape(
             lambda: train_loop.init_state(model, opt, jax.random.PRNGKey(0)))
         st_sh = shard_lib.state_shardings(state_abs, mesh)
@@ -79,7 +80,9 @@ def test_pipeline_matches_sequential():
         from jax.sharding import PartitionSpec as P
         from repro.distributed import pipeline as pp
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import auto_mesh
+
+        mesh = auto_mesh((4,), ("pod",))
         L, D, n_micro, mb = 8, 16, 8, 4
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         params = {"w": jax.random.normal(ks[0], (L, D, D), jnp.float32) * 0.1}
@@ -249,18 +252,20 @@ def test_grad_compression_wire_dtype_and_error_feedback():
     run_devs("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed import compression
         from repro.utils import hlo_analysis
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import auto_mesh
+
+        mesh = auto_mesh((8,), ("data",))
 
         def compressed_psum(g, err):
             return compression.pmean_compressed(g, err, jnp.bfloat16,
                                                 "data", 8)
 
-        f = shard_map(compressed_psum, mesh=mesh,
-                      in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data")))
+        f = jax.shard_map(compressed_psum, mesh=mesh,
+                          in_specs=(P("data"), P("data")),
+                          out_specs=(P("data"), P("data")), check_vma=False)
         g = jax.random.normal(jax.random.PRNGKey(0), (64, 128), jnp.float32)
         err = jnp.zeros((64, 128), jnp.float32)
         # check the backend-neutral IR: the CPU *backend* upcasts bf16
@@ -304,7 +309,9 @@ def test_context_parallel_decode_matches():
         tok = jnp.ones((B, 1), jnp.int32)
         ref, _ = model.decode_step(params, state, tok)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+
+        mesh = auto_mesh((4, 2), ("data", "model"))
         with mesh:
             p_sh = shard_lib.state_shardings(
                 jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))), mesh)
@@ -332,7 +339,8 @@ def test_generation_engine_lowers_on_tp_mesh():
 
         cfg = get_config("granite-3-2b", smoke=True)
         model = build_model(cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4, 2), ("data", "model"))
         params_abs = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
         p_sh = shard_lib.state_shardings(params_abs, mesh)
         batch_abs = {"tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32)}

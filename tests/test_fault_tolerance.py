@@ -112,6 +112,27 @@ class TestSupervisor:
             s, _ = step(s, batch_fn(i))
         _leaves_equal(s, final)
 
+    @pytest.mark.parametrize("status", ["RESOURCE_EXHAUSTED",
+                                        "INVALID_ARGUMENT"])
+    def test_device_oom_and_compile_errors_propagate(self, setup, status):
+        """A device OOM or a compile refusal after the first checkpoint must
+        surface, not be 'recovered' from the checkpoint and retried forever."""
+        model, opt, step, batch_fn, state, ckpt = setup
+        calls = []
+
+        def failing_step(s, batch):
+            calls.append(1)
+            if len(calls) > 2:
+                raise jax.errors.JaxRuntimeError(
+                    f"{status}: simulated device error")
+            return step(s, batch)
+
+        sup = RunSupervisor(SupervisorConfig(ckpt, ckpt_every=1))
+        with pytest.raises(jax.errors.JaxRuntimeError, match=status):
+            sup.run(state, failing_step, batch_fn, n_steps=5)
+        assert ckpt_lib.latest_step(ckpt) == 2
+        assert sup.recoveries == [] and len(calls) == 3
+
     def test_straggler_keeps_completed_state(self):
         """A late-but-successful step must NOT be rolled back: the supervisor
         keeps the completed state, records the faulting step, and the run

@@ -9,7 +9,6 @@ from repro.core.collage import CollageAdamW
 from repro.core.precision import PrecisionPolicy, Strategy
 from repro.kernels.collage_update.collage_update import collage_update
 from repro.kernels.collage_update.ref import collage_update_ref
-from repro.kernels.edq.edq import edq_metrics
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -39,6 +38,26 @@ class TestCollageUpdateKernel:
             np.testing.assert_array_equal(
                 np.asarray(got, np.float32), np.asarray(want, np.float32),
                 err_msg=f"{strategy}/{name} (n={n})")
+
+    def test_rn_bit_trick_equals_reduce_precision(self):
+        """The kernel's integer-op bf16 rounding (Mosaic has no
+        reduce_precision) ≡ lax.reduce_precision(x, 8, 7) on every f32
+        class: random bit patterns, ties, subnormals, overflow to inf."""
+        from repro.kernels.collage_update.collage_update import _rn
+        bits = jax.random.bits(jax.random.PRNGKey(0), (1 << 20,),
+                               jnp.uint32)
+        edge = jnp.array([0, 0x80000000, 0x00008000, 0x00018000, 0x3F808000,
+                          0x3F818000, 0x7F7FFFFF, 0x7F800000, 0xFF800000,
+                          0x00000001, 0x807FFFFF, 0x7F7F8000], jnp.uint32)
+        x = jax.lax.bitcast_convert_type(jnp.concatenate([bits, edge]),
+                                         jnp.float32)
+        got = jax.jit(_rn)(x)
+        want = jax.jit(lambda v: jax.lax.reduce_precision(v, 8, 7))(x)
+        nan = np.isnan(np.asarray(want))
+        np.testing.assert_array_equal(np.isnan(np.asarray(got)), nan)
+        np.testing.assert_array_equal(
+            np.asarray(jax.lax.bitcast_convert_type(got, jnp.uint32))[~nan],
+            np.asarray(jax.lax.bitcast_convert_type(want, jnp.uint32))[~nan])
 
     @pytest.mark.parametrize("block_rows", [8, 64, 256])
     def test_block_shape_sweep(self, block_rows):
@@ -79,23 +98,6 @@ class TestCollageUpdateKernel:
                 np.testing.assert_array_equal(
                     np.asarray(state_r.delta[k], np.float32),
                     np.asarray(state_f.delta[k], np.float32))
-
-
-class TestEDQKernel:
-    @pytest.mark.parametrize("n", [256, 4096, 128 * 77])
-    def test_matches_jnp(self, n):
-        k1, k2 = jax.random.split(jax.random.PRNGKey(n))
-        upd = jax.random.normal(k1, (n,), jnp.float32) * 1e-3
-        eff = jnp.where(jax.random.uniform(k2, (n,)) < 0.3, 0.0,
-                        upd + jax.random.normal(k2, (n,)) * 1e-5)
-        out = edq_metrics(upd, eff, interpret=True)
-        un = float(jnp.linalg.norm(upd))
-        want_edq = float(jnp.dot(upd, eff) / un)
-        np.testing.assert_allclose(float(out["edq"]), want_edq, rtol=1e-5)
-        np.testing.assert_allclose(float(out["update_norm"]), un, rtol=1e-5)
-        want_lost = float(100.0 * jnp.sum((jnp.abs(upd) > 0) & (eff == 0)) / n)
-        np.testing.assert_allclose(float(out["imprecision_pct"]), want_lost,
-                                   rtol=1e-6)
 
 
 class TestFlashAttentionKernel:
