@@ -163,7 +163,28 @@ def parse_args(argv=None):
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default=None)
+    ap.add_argument("--trace-dir", default=None,
+                    help="record the --trace-steps with the JAX profiler "
+                         "into this directory (README: profiling)")
+    ap.add_argument("--trace-steps", type=_step_range, default=(1, 3),
+                    metavar="A:B", help="steps A..B-1 to record")
     return ap.parse_args(argv)
+
+
+def _step_range(text: str) -> tuple:
+    a, sep, b = text.partition(":")
+    if not sep or not a.isdigit() or not b.isdigit() or int(a) >= int(b):
+        raise argparse.ArgumentTypeError(f"want A:B with A < B, got {text!r}")
+    return int(a), int(b)
+
+
+def init_state(model, opt, seed: int, grad_compression: str = "none"):
+    """The single-program step's fresh state, made by one jitted call:
+    made op by op, the init's intermediate buffers set the run's peak
+    device memory."""
+    make = jax.jit(lambda key: train_loop.init_state(
+        model, opt, key, grad_compression))
+    return make(jax.random.PRNGKey(seed))
 
 
 def main(argv=None) -> TrainRun:
@@ -209,9 +230,7 @@ def main(argv=None) -> TrainRun:
                     lambda x: x.reshape((x.shape[0] // mb, mb) + x.shape[1:]),
                     raw_batch_fn(i))
     else:
-        state = train_loop.init_state(model, opt,
-                                      jax.random.PRNGKey(args.seed),
-                                      args.grad_compression)
+        state = init_state(model, opt, args.seed, args.grad_compression)
     start = 0
     if args.resume:
         latest = ckpt_lib.latest_step(args.ckpt_dir)
@@ -228,21 +247,25 @@ def main(argv=None) -> TrainRun:
     compile_s = time.perf_counter() - t0
     print(f"compiled train step in {compile_s:.1f}s")
 
-    sup = RunSupervisor(SupervisorConfig(args.ckpt_dir, args.ckpt_every))
+    sup = RunSupervisor(SupervisorConfig(
+        args.ckpt_dir, args.ckpt_every, trace_dir=args.trace_dir,
+        trace_steps=args.trace_steps))
     history, step_s = [], []
     t0 = time.time()
 
     def logged_step(state, batch):
         ts = time.perf_counter()
-        state, metrics = jax.block_until_ready(compiled(state, batch))
+        with jax.profiler.TraceAnnotation("repro.step"):
+            state, metrics = jax.block_until_ready(compiled(state, batch))
         step_s.append(time.perf_counter() - ts)
-        step = int(state.opt_state.step)
-        if step % args.log_every == 0 or step == 1:
-            m = {k: float(v) for k, v in metrics.items()}
-            m["step"] = step
-            history.append(m)
-            print(f"step {step:5d} loss {m['loss']:.4f} ppl {m['ppl']:.2f} "
-                  f"edq {m.get('edq', 0):.3e} impr% {m.get('imprecision_pct', 0):.2f}")
+        with jax.profiler.TraceAnnotation("repro.log"):
+            step = int(state.opt_state.step)
+            if step % args.log_every == 0 or step == 1:
+                m = {k: float(v) for k, v in metrics.items()}
+                m["step"] = step
+                history.append(m)
+                print(f"step {step:5d} loss {m['loss']:.4f} ppl {m['ppl']:.2f} "
+                      f"edq {m.get('edq', 0):.3e} impr% {m.get('imprecision_pct', 0):.2f}")
         return state, metrics
 
     state, step, _ = sup.run(state, logged_step, batch_fn, args.steps,

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import transformer as tf
 from repro.models.layers import ACC, embed_init, embed_lookup, rms_norm, rms_norm_init
+from repro.train import scopes
 
 PyTree = Any
 
@@ -206,9 +207,10 @@ class Model:
     # ------------------------------------------------------------ helpers --
     def _head(self, params, x):
         cfg = self.cfg
-        x = rms_norm(x, params["decoder"]["final_norm"], cfg.norm_eps)
-        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        return jnp.matmul(x, w, preferred_element_type=ACC)  # logits fp32
+        with jax.named_scope(scopes.HEAD):
+            x = rms_norm(x, params["decoder"]["final_norm"], cfg.norm_eps)
+            w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+            return jnp.matmul(x, w, preferred_element_type=ACC)  # logits fp32
 
     def _encode(self, params, frontend):
         cfg = self.cfg
@@ -220,10 +222,11 @@ class Model:
     def _decoder_input(self, params, batch):
         """Token embeddings, with the VLM patch prefix concatenated."""
         cfg = self.cfg
-        x = embed_lookup(params["embed"], batch["tokens"])
-        if cfg.family == "vlm":
-            x = jnp.concatenate([batch["frontend"].astype(x.dtype), x], axis=1)
-        return x
+        with jax.named_scope(scopes.EMBED):
+            x = embed_lookup(params["embed"], batch["tokens"])
+            if cfg.family == "vlm":
+                x = jnp.concatenate([batch["frontend"].astype(x.dtype), x], axis=1)
+            return x
 
     @property
     def _prefix_len(self) -> int:
@@ -256,14 +259,15 @@ class Model:
         The single definition of the training objective: ``loss`` and the
         sharded engine's pipelined loss (train/sharded.py) both call it, so
         masking/shift changes cannot silently diverge between paths."""
-        logits = logits[..., :-1, :]
-        targets = labels[..., 1:]
-        mask = (targets >= 0).astype(ACC)
-        logp = jax.nn.log_softmax(logits.astype(ACC), axis=-1)
-        ll = jnp.take_along_axis(
-            logp, jnp.maximum(targets, 0)[..., None], axis=-1)[..., 0]
-        ntok = jnp.maximum(mask.sum(), 1.0)
-        return -(ll * mask).sum() / ntok
+        with jax.named_scope(scopes.HEAD):
+            logits = logits[..., :-1, :]
+            targets = labels[..., 1:]
+            mask = (targets >= 0).astype(ACC)
+            logp = jax.nn.log_softmax(logits.astype(ACC), axis=-1)
+            ll = jnp.take_along_axis(
+                logp, jnp.maximum(targets, 0)[..., None], axis=-1)[..., 0]
+            ntok = jnp.maximum(mask.sum(), 1.0)
+            return -(ll * mask).sum() / ntok
 
     def loss(self, params, batch, remat: str = "none"):
         """Next-token cross entropy (fp32), MoE aux added; returns

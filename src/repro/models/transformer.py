@@ -24,6 +24,7 @@ from repro.models import rwkv as rwkv_lib
 from repro.models import ssm as ssm_lib
 from repro.models.layers import (ACC, mlp_apply, mlp_init, rms_norm,
                                  rms_norm_init)
+from repro.train import scopes
 
 # ---------------------------------------------------------------------------
 # ambient activation-sharding context (no-op outside pjit launch)
@@ -79,8 +80,18 @@ def _residual(p, x, cfg, fn):
     return fn(h)
 
 
+_SCOPES = {"attn": scopes.ATTENTION, "cross_attn": scopes.ATTENTION,
+           "mlp": scopes.MLP, "moe": scopes.MLP}
+
+
 def sub_apply(p, x, sub: Sub, cfg: ModelConfig, memory=None, positions=None):
     """Returns (x_out, aux_loss)."""
+    scope = _SCOPES.get(sub.kind)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        return _sub_apply(p, x, sub, cfg, memory, positions)
+
+
+def _sub_apply(p, x, sub: Sub, cfg: ModelConfig, memory, positions):
     aux = jnp.zeros((), ACC)
     if sub.kind == "attn":
         impl = cfg.attention_impl

@@ -47,6 +47,8 @@ class SupervisorConfig:
     keep_last: int = 3
     deadline_slack: float = 3.0
     min_step_time: float = 1e-3
+    trace_dir: Optional[str] = None   # profile steps trace_steps[0]..[1]−1
+    trace_steps: tuple = (0, 0)
 
 
 class RunSupervisor:
@@ -86,48 +88,76 @@ class RunSupervisor:
             start_step: int = 0, template=None):
         """Run to ``n_steps``, checkpointing and recovering on faults.
 
+        Each iteration is a profiler step (``StepTraceAnnotation("train")``)
+        holding the host spans ``repro.batch`` and ``repro.checkpoint``;
+        with ``cfg.trace_dir`` the profiler records steps
+        ``cfg.trace_steps`` = (A, B), A..B−1, into that directory.
+
         template: pytree template for elastic restore (defaults to state)."""
         step = start_step
         last_metrics = None
-        while step < n_steps:
-            t0 = time.monotonic()
-            try:
-                if self.fault_hook is not None:
-                    self.fault_hook(step)
+        first, end = self.cfg.trace_steps
+        tracing = traced = False
+        try:
+            while step < n_steps:
+                if (self.cfg.trace_dir and not traced
+                        and first <= step < end):
+                    jax.profiler.start_trace(self.cfg.trace_dir)
+                    tracing = traced = True
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    state, step, last_metrics = self._step(
+                        state, step, train_step, batch_fn, n_steps,
+                        template, last_metrics)
+                if tracing and step >= end:
+                    jax.profiler.stop_trace()
+                    tracing = False
+        finally:
+            if tracing:
+                jax.profiler.stop_trace()
+        return state, step, last_metrics
+
+    def _step(self, state, step, train_step, batch_fn, n_steps, template,
+              last_metrics):
+        """One iteration of ``run``: (state, next step, metrics)."""
+        t0 = time.monotonic()
+        try:
+            if self.fault_hook is not None:
+                self.fault_hook(step)
+            with jax.profiler.TraceAnnotation("repro.batch"):
                 batch = batch_fn(step)
-                state, last_metrics = train_step(state, batch)
-            except (RuntimeError, TimeoutError) as e:  # real crash
-                if _fatal(e):
-                    raise
-                restore_step = ckpt_lib.latest_step(self.cfg.ckpt_dir)
-                if restore_step is None:
-                    raise RuntimeError("fault before first checkpoint") from e
-                self.recoveries.append(step)       # the FAULTING step
-                # layout-elastic: migrates bucketed states whose bucket
-                # partitioning changed with the re-scaled mesh (no-op for
-                # tree-layout states)
-                state, extra = ckpt_lib.restore_bucketed(
-                    self.cfg.ckpt_dir, restore_step, template or state)
-                step = extra["step"]
-                continue
-            dt = time.monotonic() - t0
-            deadline = self.deadline()
-            if dt > deadline:
-                # late but SUCCESSFUL: the new state is valid — keep it and
-                # flag the incident (re-mesh policy hooks read these). The
-                # sample enters the p99 window CLAMPED to the deadline: a
-                # one-off outlier can't poison the window, but a genuine
-                # regime change (re-meshed smaller, slower hosts) ratchets
-                # the deadline up by ~slack× per window refresh instead of
-                # flagging every step forever.
-                self.recoveries.append(step)
-                self.stragglers.append(step)
-                self.step_times.append(deadline)
-            else:
-                self.step_times.append(dt)
-            step += 1
-            if self.cfg.ckpt_every > 0 and (step % self.cfg.ckpt_every == 0
-                                            or step == n_steps):
+            state, last_metrics = train_step(state, batch)
+        except (RuntimeError, TimeoutError) as e:  # real crash
+            if _fatal(e):
+                raise
+            restore_step = ckpt_lib.latest_step(self.cfg.ckpt_dir)
+            if restore_step is None:
+                raise RuntimeError("fault before first checkpoint") from e
+            self.recoveries.append(step)       # the FAULTING step
+            # layout-elastic: migrates bucketed states whose bucket
+            # partitioning changed with the re-scaled mesh (no-op for
+            # tree-layout states)
+            state, extra = ckpt_lib.restore_bucketed(
+                self.cfg.ckpt_dir, restore_step, template or state)
+            return state, extra["step"], last_metrics
+        dt = time.monotonic() - t0
+        deadline = self.deadline()
+        if dt > deadline:
+            # late but SUCCESSFUL: the new state is valid — keep it and
+            # flag the incident (re-mesh policy hooks read these). The
+            # sample enters the p99 window CLAMPED to the deadline: a
+            # one-off outlier can't poison the window, but a genuine
+            # regime change (re-meshed smaller, slower hosts) ratchets
+            # the deadline up by ~slack× per window refresh instead of
+            # flagging every step forever.
+            self.recoveries.append(step)
+            self.stragglers.append(step)
+            self.step_times.append(deadline)
+        else:
+            self.step_times.append(dt)
+        step += 1
+        if self.cfg.ckpt_every > 0 and (step % self.cfg.ckpt_every == 0
+                                        or step == n_steps):
+            with jax.profiler.TraceAnnotation("repro.checkpoint"):
                 ckpt_lib.save(self.cfg.ckpt_dir, step, state,
                               keep_last=self.cfg.keep_last,
                               extra={"step": step})

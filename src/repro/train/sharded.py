@@ -94,7 +94,7 @@ from repro.distributed import sharding as shard_lib
 from repro.models import transformer as tf
 from repro.models.layers import embed_lookup
 from repro.models.model import AUX_LOSS_COEF, Model
-from repro.train import train_loop
+from repro.train import scopes, train_loop
 
 Axis = Union[str, tuple]
 
@@ -473,9 +473,10 @@ def make_sharded_train_step(model: Model, opt: CollageAdamW, mesh: Mesh, *,
         params = state.params
         grad_err = state.grad_err
         if bucketed and zero_shard:
-            full = bucketing.BucketedParams(
-                tuple(jax.lax.all_gather(d, axis, tiled=True)
-                      for d in params.data), params.layout)
+            with jax.named_scope(scopes.PARAM_GATHER):
+                full = bucketing.BucketedParams(
+                    tuple(jax.lax.all_gather(d, axis, tiled=True)
+                          for d in params.data), params.layout)
         else:
             full = params
         loss, lmetrics, grads = accum(full, batch)
@@ -499,18 +500,19 @@ def make_sharded_train_step(model: Model, opt: CollageAdamW, mesh: Mesh, *,
             new_rows: list = [None] * params.layout.n_buckets
 
             def reduce_bucket(i, g):
-                if dtype is not None:
-                    e = err_rows[i] if use_ef else None
-                    red = compression.psum_scatter_compressed if zero_shard \
-                        else compression.pmean_compressed
-                    m, r = red(g, e, dtype, axis, n_dp)
-                    new_rows[i] = r
-                    return m.astype(g.dtype)
-                if zero_shard:
-                    return (jax.lax.psum_scatter(
-                        g.astype(jnp.float32), axis, scatter_dimension=0,
-                        tiled=True) / n_dp).astype(g.dtype)
-                return pmean32(g, axis)
+                with jax.named_scope(scopes.GRAD_REDUCE):
+                    if dtype is not None:
+                        e = err_rows[i] if use_ef else None
+                        red = compression.psum_scatter_compressed \
+                            if zero_shard else compression.pmean_compressed
+                        m, r = red(g, e, dtype, axis, n_dp)
+                        new_rows[i] = r
+                        return m.astype(g.dtype)
+                    if zero_shard:
+                        return (jax.lax.psum_scatter(
+                            g.astype(jnp.float32), axis, scatter_dimension=0,
+                            tiled=True) / n_dp).astype(g.dtype)
+                    return pmean32(g, axis)
 
             offs = None
             if zero_shard and opt.policy.strategy is Strategy.SR:
@@ -522,40 +524,43 @@ def make_sharded_train_step(model: Model, opt: CollageAdamW, mesh: Mesh, *,
                 idx = jax.lax.axis_index(axis).astype(jnp.uint32)
                 offs = tuple(idx * jnp.uint32(b.padded // n_dp)
                              for b in params.layout.buckets)
-            if zero_shard and opt.compute_metrics:
-                # cross-shard StepMetrics: the optimizer exports its RAW
-                # (5,) metric partials (kernels.collage_update.ops), the
-                # engine psums them over the dp axis and finalizes ONCE —
-                # definitionally exact, no hand-maintained inverse of the
-                # finalize step
-                new_params, new_opt, parts = opt.step_bucketed(
-                    grads.data, params, opt_state, metrics_partials=True,
-                    elem_offsets=offs, reduce_fn=reduce_bucket)
-                om = kops.finalize_metrics(jax.lax.psum(parts, axis),
-                                           params.layout.total_size)
-            else:
-                new_params, new_opt, om = opt.step_bucketed(
-                    grads.data, params, opt_state, elem_offsets=offs,
-                    reduce_fn=reduce_bucket)
+            with jax.named_scope(scopes.OPTIMIZER):
+                if zero_shard and opt.compute_metrics:
+                    # cross-shard StepMetrics: the optimizer exports its RAW
+                    # (5,) metric partials (kernels.collage_update.ops), the
+                    # engine psums them over the dp axis and finalizes ONCE
+                    # — definitionally exact, no hand-maintained inverse of
+                    # the finalize step
+                    new_params, new_opt, parts = opt.step_bucketed(
+                        grads.data, params, opt_state, metrics_partials=True,
+                        elem_offsets=offs, reduce_fn=reduce_bucket)
+                    om = kops.finalize_metrics(jax.lax.psum(parts, axis),
+                                               params.layout.total_size)
+                else:
+                    new_params, new_opt, om = opt.step_bucketed(
+                        grads.data, params, opt_state, elem_offsets=offs,
+                        reduce_fn=reduce_bucket)
             if use_ef and dtype is not None:
                 new_opt = dataclasses.replace(
                     new_opt, grad_err=tuple(r[None] for r in new_rows))
         else:
-            if dtype is not None:
-                # residual leaves carry a per-device dim: strip this
-                # device's row for the shared leaf-wise reducer, restore it
-                # for the out specs
-                err_plain = jax.tree_util.tree_map(lambda e: e[0], grad_err) \
-                    if use_ef else None
-                grads, new_err = compression.pmean_compressed_tree(
-                    grads, err_plain, dtype, axis, n_dp)
-                if use_ef:
-                    grad_err = jax.tree_util.tree_map(lambda r: r[None],
-                                                      new_err)
-            else:
-                grads = jax.tree_util.tree_map(lambda g: pmean32(g, axis),
-                                               grads)
-            new_params, new_opt, om = opt.step(grads, params, opt_state)
+            with jax.named_scope(scopes.GRAD_REDUCE):
+                if dtype is not None:
+                    # residual leaves carry a per-device dim: strip this
+                    # device's row for the shared leaf-wise reducer,
+                    # restore it for the out specs
+                    err_plain = jax.tree_util.tree_map(
+                        lambda e: e[0], grad_err) if use_ef else None
+                    grads, new_err = compression.pmean_compressed_tree(
+                        grads, err_plain, dtype, axis, n_dp)
+                    if use_ef:
+                        grad_err = jax.tree_util.tree_map(lambda r: r[None],
+                                                          new_err)
+                else:
+                    grads = jax.tree_util.tree_map(
+                        lambda g: pmean32(g, axis), grads)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_params, new_opt, om = opt.step(grads, params, opt_state)
         return (train_loop.TrainState(new_params, new_opt, grad_err),
                 _metric_dict(loss, lmetrics, om))
 

@@ -35,6 +35,7 @@ from repro.core import bucketing
 from repro.core.collage import CollageAdamW
 from repro.distributed import compression
 from repro.models.model import Model
+from repro.train import scopes
 
 
 @jax.tree_util.register_pytree_with_keys_class
@@ -125,8 +126,10 @@ def make_accum_grads(model: Model, *, microbatch: int = 0,
     def loss_fn(params, batch):
         if isinstance(params, bucketing.BucketedParams):
             # model-apply boundary: the ONLY place bucket views materialize
-            return model.loss(params.tree(), batch, remat=remat)
-        return model.loss(params, batch, remat=remat)
+            with jax.named_scope(scopes.BUCKET_VIEWS):
+                params = params.tree()
+        with jax.named_scope(scopes.FORWARD):
+            return model.loss(params, batch, remat=remat)
 
     def grads_of(params, batch):
         (loss, metrics), grads = jax.value_and_grad(
@@ -238,8 +241,9 @@ def make_train_step(model: Model, opt: CollageAdamW, *,
                         grad_err = new_err
         elif psum_axis is not None:
             grads = jax.lax.pmean(grads, psum_axis)
-        params, opt_state, ometrics = _apply_opt(opt, grads, state.params,
-                                                 opt_state)
+        with jax.named_scope(scopes.OPTIMIZER):
+            params, opt_state, ometrics = _apply_opt(opt, grads,
+                                                     state.params, opt_state)
         metrics = {"loss": loss, **lmetrics,
                    "edq": ometrics.edq, "update_norm": ometrics.update_norm,
                    "imprecision_pct": ometrics.imprecision_pct,
