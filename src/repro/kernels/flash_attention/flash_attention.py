@@ -32,6 +32,13 @@ same entry point runs tier-1 CI on CPU and compiles to Mosaic on device.
 Oracle = ``ref.attention_ref`` (full masked softmax), forward AND
 ``jax.grad`` swept in tests/test_flash_vjp.py.
 
+Every kernel walks its tiles with ``_tile_loop``: only the tiles that
+straddle the causal diagonal, the window's lower edge or the valid length
+build the mask (``_tile_ranges``), and the others run in unrolled groups
+so the scheduler overlaps one tile's chain of products and softmax with
+the next's. bf16 inputs reach the MXU as bf16 tiles (``_dot``); any f32
+input keeps every tile f32. DESIGN.md §7 says how the MXU is fed.
+
 Row statistics (LSE, D) cross the kernel boundary as (B, H, L, 128) f32,
 the row value replicated across the 128 lanes: a per-row block is then a
 tile-legal (blk, 128) slab, and ``_col`` reads its first lane as the
@@ -51,7 +58,15 @@ from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 F32 = jnp.float32
+BF16 = jnp.bfloat16
 STAT_LANES = 128   # lane width of the replicated LSE / D kernel operands
+
+# contraction forms of a 2-D product: the MXU reads a transposed right
+# operand natively, so Kᵀ and Vᵀ are never materialised
+NN = (((1,), (0,)), ((), ()))    # a · b
+NT = (((1,), (1,)), ((), ()))    # a · bᵀ
+TN = (((0,), (0,)), ((), ()))    # aᵀ · b
+UNROLL = (8, 4, 2) # group sizes of a kernel's unmasked tiles (PERF.md §6)
 
 
 def _wide(x):
@@ -62,6 +77,23 @@ def _wide(x):
 def _col(ref_block):
     """(blk, 128) replicated statistic block → (blk, 1) column."""
     return ref_block[:, 0:1]
+
+
+def _mxu_dtype(*xs):
+    """Operand dtype of the kernels' products: bf16 when every input is
+    bf16, else f32 (every tile up-cast, as the f32 path always was)."""
+    return BF16 if all(x.dtype == BF16 for x in xs) else F32
+
+
+def _dot(a, b, dims):
+    """One MXU product of two tiles of the operand dtype, f32 result.
+
+    An f32 side (P, dS) is cast to the operand dtype first. For bf16 that
+    is one round-to-nearest cast, which is what the v5e MXU does to an f32
+    operand at Mosaic's default precision (one bf16 pass, PERF.md §6): the
+    bf16 path computes the f32 path's products from half the bytes."""
+    return jax.lax.dot_general(a.astype(b.dtype), b, dims,
+                               preferred_element_type=F32)
 
 
 def _band_lo_block(qi, blk_q: int, blk_k: int, window: int):
@@ -87,36 +119,99 @@ def _mask(s_shape, q0, k0, *, causal: bool, window: int, valid_len: int):
     return bad
 
 
+def _tile_ranges(i, *, over_keys: bool, blk_q: int, blk_k: int,
+                 seq_len: int, causal: bool, window: int, valid_len: int):
+    """The tiles one program visits, as (lo, a, b, hi): it loops over
+    [lo, hi); the tiles in [a, b) hold no masked pair and skip the mask,
+    those in [lo, a) and [b, hi) straddle the causal diagonal, the
+    window's lower edge or ``valid_len`` and build it.
+
+    ``over_keys``: ``i`` is a query block and the tiles are key blocks
+    (forward, dQ); else ``i`` is a key block and the tiles are query
+    blocks (dK/dV). Works on Python ints and on traced scalars alike."""
+    if over_keys:
+        q0 = i * blk_q
+        lo = _band_lo_block(i, blk_q, blk_k, window) if window else 0
+        # causal: skip key blocks strictly after this query block
+        hi = pl.cdiv(q0 + blk_q, blk_k) if causal else seq_len // blk_k
+        # key block ≥ a: every key is above every query's band edge
+        a = pl.cdiv(jnp.maximum(q0 + blk_q - window, 0), blk_k) \
+            if window else lo
+        b = hi
+        if causal:           # last key of the block ≤ first query
+            b = jnp.minimum(b, (q0 + 1) // blk_k)
+        if valid_len:        # every key of the block < valid_len
+            b = jnp.minimum(b, valid_len // blk_k)
+    else:
+        k0 = i * blk_k
+        nq = seq_len // blk_q
+        # causal: no query before this key block attends into it; window:
+        # no query past the band's upper edge does either
+        lo = k0 // blk_q if causal else 0
+        hi = jnp.minimum(nq, (k0 + blk_k + window - 2) // blk_q + 1) \
+            if window else nq
+        # query block ≥ a: its first query ≥ the key block's last key
+        a = pl.cdiv(k0 + blk_k - 1, blk_q) if causal else lo
+        # query block < b: its last query within the band of the first key
+        b = jnp.minimum(hi, (k0 + window) // blk_q) if window else hi
+        if valid_len:        # a key block reaching valid_len: all masked
+            b = jnp.where(k0 + blk_k <= valid_len, b, lo)
+    a = jnp.clip(a, lo, hi)
+    return lo, a, jnp.clip(b, a, hi), hi
+
+
+def _tile_loop(body, ranges, carry):
+    """Run ``body(j, carry, masked=...)`` over a program's tiles in order:
+    the masked head, the unmasked middle, the masked tail. The middle runs
+    in groups of ``UNROLL[0]`` tiles an iteration, what is left in groups
+    of the next size, the last tiles one at a time. One tile alone is a
+    chain of dependent products, reductions and exponentials whose latency
+    sets its time; the scheduler overlaps the chains of a group."""
+    lo, a, b, hi = ranges
+    masked = functools.partial(body, masked=True)
+    plain = functools.partial(body, masked=False)
+    carry = jax.lax.fori_loop(lo, a, masked, carry)
+    for size in UNROLL:
+        def group(t, c, size=size, start=a):
+            for u in range(size):
+                c = plain(start + size * t + u, c)
+            return c
+        n = (b - a) // size
+        carry = jax.lax.fori_loop(0, n, group, carry)
+        a = a + size * n
+    carry = jax.lax.fori_loop(a, b, plain, carry)
+    return jax.lax.fori_loop(b, hi, masked, carry)
+
+
 # --------------------------------------------------------------- forward --
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, blk_q: int,
                 blk_k: int, seq_len: int, causal: bool, window: int,
-                scale: float, valid_len: int):
+                scale: float, valid_len: int, mxu):
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(F32)                          # (blk_q, dh)
-    nk = seq_len // blk_k
+    q = q_ref[0, 0].astype(mxu)                          # (blk_q, dh)
     m = jnp.full((blk_q, 1), NEG_INF, F32)               # row max (column)
     l = jnp.zeros((blk_q, 1), F32)                       # row sum (column)
     acc = jnp.zeros((blk_q, q.shape[-1]), F32)
 
-    def body(kj, carry):
+    def body(kj, carry, *, masked):
         m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(F32)
-        v = v_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(F32)
-        s = (q @ k.T) * scale                             # (blk_q, blk_k)
-        bad = _mask(s.shape, qi * blk_q, kj * blk_k, causal=causal,
-                    window=window, valid_len=valid_len)
-        s = jnp.where(bad, NEG_INF, s)
+        k = k_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(mxu)
+        v = v_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(mxu)
+        s = _dot(q, k, NT) * scale                        # (blk_q, blk_k)
+        if masked:
+            s = jnp.where(_mask(s.shape, qi * blk_q, kj * blk_k,
+                                causal=causal, window=window,
+                                valid_len=valid_len), NEG_INF, s)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=1, keepdims=True)
-        acc_new = acc * corr + p @ v
+        acc_new = acc * corr + _dot(p, v, NN)
         return m_new, l_new, acc_new
 
-    # causal: skip key blocks strictly after this query block
-    n_iter = pl.cdiv((qi + 1) * blk_q, blk_k) if causal else nk
-    lo = _band_lo_block(qi, blk_q, blk_k, window) if window else 0
-    m, l, acc = jax.lax.fori_loop(lo, n_iter, body, (m, l, acc))
+    m, l, acc = _tile_loop(body, _tile_ranges(
+        qi, over_keys=True, blk_q=blk_q, blk_k=blk_k, seq_len=seq_len,
+        causal=causal, window=window, valid_len=valid_len), (m, l, acc))
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     # fully-masked (padded) rows: m never left NEG_INF (l is NOT a valid
     # detector — every masked tile contributes p = exp(NEG_INF − NEG_INF)
@@ -134,7 +229,8 @@ def _fwd_call(q, k, v, *, causal, window, blk_q, blk_k, valid_len,
     scale = dh ** -0.5
     kernel = functools.partial(_fwd_kernel, blk_q=blk_q, blk_k=blk_k,
                                seq_len=L, causal=causal, window=window,
-                               scale=scale, valid_len=valid_len)
+                               scale=scale, valid_len=valid_len,
+                               mxu=_mxu_dtype(q, k, v))
     o, lse = pl.pallas_call(
         kernel,
         grid=(B, H, L // blk_q),
@@ -160,68 +256,65 @@ def _fwd_call(q, k, v, *, causal, window, blk_q, blk_k, valid_len,
 # -------------------------------------------------------------- backward --
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                blk_q: int, blk_k: int, seq_len: int, causal: bool,
-               window: int, scale: float, valid_len: int):
+               window: int, scale: float, valid_len: int, mxu):
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(F32)                          # (blk_q, dh)
-    do = do_ref[0, 0].astype(F32)
+    q = q_ref[0, 0].astype(mxu)                          # (blk_q, dh)
+    do = do_ref[0, 0].astype(mxu)
     lse = _col(lse_ref[0, 0])                            # (blk_q, 1)
     delta = _col(delta_ref[0, 0])
 
-    def body(kj, acc):
-        k = k_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(F32)
-        v = v_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(F32)
-        s = (q @ k.T) * scale
-        bad = _mask(s.shape, qi * blk_q, kj * blk_k, causal=causal,
-                    window=window, valid_len=valid_len)
-        s = jnp.where(bad, NEG_INF, s)
+    def body(kj, acc, *, masked):
+        k = k_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(mxu)
+        v = v_ref[0, 0, pl.ds(kj * blk_k, blk_k), :].astype(mxu)
+        s = _dot(q, k, NT) * scale
+        if masked:
+            s = jnp.where(_mask(s.shape, qi * blk_q, kj * blk_k,
+                                causal=causal, window=window,
+                                valid_len=valid_len), NEG_INF, s)
         p = jnp.exp(s - lse)                             # masked → exactly 0
-        dp = do @ v.T                                    # (blk_q, blk_k)
+        dp = _dot(do, v, NT)                             # (blk_q, blk_k)
         ds = p * (dp - delta)
-        return acc + ds @ k
+        return acc + _dot(ds, k, NN)
 
-    n_iter = pl.cdiv((qi + 1) * blk_q, blk_k) if causal \
-        else seq_len // blk_k
-    lo = _band_lo_block(qi, blk_q, blk_k, window) if window else 0
-    acc = jax.lax.fori_loop(lo, n_iter, body,
-                            jnp.zeros((blk_q, q.shape[-1]), F32))
+    acc = _tile_loop(body, _tile_ranges(
+        qi, over_keys=True, blk_q=blk_q, blk_k=blk_k, seq_len=seq_len,
+        causal=causal, window=window, valid_len=valid_len),
+        jnp.zeros((blk_q, q.shape[-1]), F32))
     dq_ref[0, 0] = acc * scale
 
 
 def _dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                 dk_ref, dv_ref, *, blk_q: int, blk_k: int, seq_len: int,
-                causal: bool, window: int, scale: float, valid_len: int):
+                causal: bool, window: int, scale: float, valid_len: int,
+                mxu):
     kj = pl.program_id(2)
     g = pl.program_id(3)                                 # GQA group member
-    k = k_ref[0, 0].astype(F32)                          # (blk_k, dh)
-    v = v_ref[0, 0].astype(F32)
+    k = k_ref[0, 0].astype(mxu)                          # (blk_k, dh)
+    v = v_ref[0, 0].astype(mxu)
     dh = k.shape[-1]
-    nq = seq_len // blk_q
 
-    def body(qi, carry):
+    def body(qi, carry, *, masked):
         dk, dv = carry
-        q = q_ref[0, 0, pl.ds(qi * blk_q, blk_q), :].astype(F32)
-        do = do_ref[0, 0, pl.ds(qi * blk_q, blk_q), :].astype(F32)
+        q = q_ref[0, 0, pl.ds(qi * blk_q, blk_q), :].astype(mxu)
+        do = do_ref[0, 0, pl.ds(qi * blk_q, blk_q), :].astype(mxu)
         lse = _col(lse_ref[0, 0, pl.ds(qi * blk_q, blk_q), :])
         delta = _col(delta_ref[0, 0, pl.ds(qi * blk_q, blk_q), :])
-        s = (q @ k.T) * scale                            # (blk_q, blk_k)
-        bad = _mask(s.shape, qi * blk_q, kj * blk_k, causal=causal,
-                    window=window, valid_len=valid_len)
-        s = jnp.where(bad, NEG_INF, s)
+        s = _dot(q, k, NT) * scale                       # (blk_q, blk_k)
+        if masked:
+            s = jnp.where(_mask(s.shape, qi * blk_q, kj * blk_k,
+                                causal=causal, window=window,
+                                valid_len=valid_len), NEG_INF, s)
         p = jnp.exp(s - lse)
-        dv = dv + p.T @ do
-        dp = do @ v.T
+        dv = dv + _dot(p, do, TN)
+        dp = _dot(do, v, NT)
         ds = p * (dp - delta)
-        dk = dk + ds.T @ q
+        dk = dk + _dot(ds, q, TN)
         return dk, dv
 
-    # causal: no query before this key block attends into it; window: no
-    # query past the band's upper edge does either
-    lo = (kj * blk_k) // blk_q if causal else 0
-    hi = jnp.minimum(nq, ((kj + 1) * blk_k + window - 2) // blk_q + 1) \
-        if window else nq
-    dk, dv = jax.lax.fori_loop(
-        lo, hi, body, (jnp.zeros((blk_k, dh), F32),
-                       jnp.zeros((blk_k, dh), F32)))
+    dk, dv = _tile_loop(body, _tile_ranges(
+        kj, over_keys=False, blk_q=blk_q, blk_k=blk_k, seq_len=seq_len,
+        causal=causal, window=window, valid_len=valid_len),
+        (jnp.zeros((blk_k, dh), F32), jnp.zeros((blk_k, dh), F32)))
     dk = dk * scale
 
     # the ``group`` grid dim revisits this output block once per q head of
@@ -247,7 +340,8 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, window, blk_q, blk_k,
     delta = _wide((do.astype(F32) * o.astype(F32)).sum(-1))
     lse = _wide(lse)
     kw = dict(blk_q=blk_q, blk_k=blk_k, seq_len=L, causal=causal,
-              window=window, scale=scale, valid_len=valid_len)
+              window=window, scale=scale, valid_len=valid_len,
+              mxu=_mxu_dtype(q, k, v, do))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),

@@ -1,8 +1,9 @@
 """The training path's Pallas kernels compile for a TPU v5e chip.
 
-Each case compiles one kernel at granite-3-2b widths for a described
-(not attached) v5e chip and asserts the Mosaic kernel is in the compiled
-program (``tpu_custom_call``): what interpret-mode tests cannot show —
+Each case compiles one kernel at granite-3-2b widths (flash also at
+internlm2-1.8b's, head dim 128) for a described (not attached) v5e chip
+and asserts the Mosaic kernel is in the compiled program
+(``tpu_custom_call``): what interpret-mode tests cannot show —
 block shapes the (8, 128) tiling refuses, primitives Mosaic cannot lower,
 VMEM overflow. Nothing runs, so no result or time is checked here.
 
@@ -22,6 +23,7 @@ from repro.kernels.flash_attention.flash_attention import flash_mha
 
 BUCKET = 4 * 1024 * 1024            # elements of one optimizer bucket
 B, H, HKV, L, DH = 1, 32, 8, 4096, 64   # granite-3-2b heads at L=4096
+INTERNLM2 = (16, 8, 2048, 128)          # internlm2-1.8b heads at L=2048
 
 
 @pytest.fixture(scope="module")
@@ -71,11 +73,17 @@ def test_collage_update_compiles(one_chip, code, metrics):
     _assert_kernel(compiled, "collage_update")
 
 
-@pytest.mark.parametrize("window", [0, 1024])
-@pytest.mark.parametrize("pass_", ["fwd", "bwd"])
-def test_flash_compiles(one_chip, pass_, window):
-    q = _spec((B, H, L, DH), jnp.bfloat16, one_chip)
-    kv = _spec((B, HKV, L, DH), jnp.bfloat16, one_chip)
+# granite's cases keep their ids; internlm2 adds head dim 128 (bf16 tiles
+# and the products' dimension numbers at both head widths)
+@pytest.mark.parametrize("dims,pass_,window", [
+    pytest.param((H, HKV, L, DH), p, w, id=f"{p}-{w}")
+    for p in ("fwd", "bwd") for w in (0, 1024)] + [
+    pytest.param(INTERNLM2, p, w, id=f"internlm2-{p}-{w}")
+    for p in ("fwd", "bwd") for w in (0, 1024)])
+def test_flash_compiles(one_chip, dims, pass_, window):
+    h, hkv, seq, dh = dims
+    q = _spec((B, h, seq, dh), jnp.bfloat16, one_chip)
+    kv = _spec((B, hkv, seq, dh), jnp.bfloat16, one_chip)
 
     def fwd(q, k, v):
         return flash_mha(q, k, v, causal=True, window=window,
