@@ -11,6 +11,10 @@ that stands still leaves the device fed. Nothing compiles inside the
 window. After it: peak device memory, then the plain
 reference (the program's state freed) and the comparison.
 
+What belongs to the model's architecture comes from the cell's family
+(``chipbench/families``): its named tensors, their place in the program's
+parameter tree, the check of the program's widths, and the reference.
+
 Checkpoint I/O and the launcher's ``RunSupervisor`` are outside the
 window: a run saves nothing."""
 from __future__ import annotations
@@ -28,8 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from chipbench import compare, data, spec, trace as trace_lib, weights
-from chipbench.reference import dense_gqa
+from chipbench import compare, data, scopes, spec, trace as trace_lib, weights
+from chipbench.reference import adamw
 
 CHECK_STEPS = 3        # set-up steps the output check reads
 AHEAD_S = 5.0          # seconds of steps in flight behind the one waited on
@@ -48,38 +52,9 @@ def seed_key(seed: int):
     return jnp.array([seed >> 32, seed & 0xFFFFFFFF], jnp.uint32)
 
 
-# ---------------------------------------------------------------------------
-# the program's parameter tree <-> the benchmark's named tensors
-# ---------------------------------------------------------------------------
-
-ATTN = ("wq", "wk", "wv", "wo")
-MLP = ("w_gate", "w_up", "w_down")
-
-
-def to_program(w: dict) -> dict:
-    """Named tensors → the program's parameter tree (``Model.init``)."""
-    layer = {"sub0": {"norm": w["attn_norm"], **{k: w[k] for k in ATTN}},
-             "sub1": {"norm": w["mlp_norm"], **{k: w[k] for k in MLP}}}
-    p = {"embed": w["embed"],
-         "decoder": {"groups": [layer], "final_norm": w["final_norm"]}}
-    if "lm_head" in w:
-        p["lm_head"] = w["lm_head"]
-    return p
-
-
-def from_program(p: dict) -> dict:
-    (layer,) = p["decoder"]["groups"]
-    w = {"embed": p["embed"], "final_norm": p["decoder"]["final_norm"],
-         "attn_norm": layer["sub0"]["norm"], "mlp_norm": layer["sub1"]["norm"],
-         **{k: layer["sub0"][k] for k in ATTN},
-         **{k: layer["sub1"][k] for k in MLP}}
-    if "lm_head" in p:
-        w["lm_head"] = p["lm_head"]
-    return w
-
-
 def program_argv(cell: spec.Cell, precision: str | None = None) -> list:
-    """The launcher's arguments for this cell."""
+    """The launcher's arguments for this cell: the traffic's, then the
+    configuration's own ``launcher`` list (a cut its family needs)."""
     c, t = cell.config, cell.traffic
     argv = ["--arch", c["arch"], "--layers", str(c["num_hidden_layers"]),
             "--seq-len", str(t["seq_len"]),
@@ -87,6 +62,7 @@ def program_argv(cell: spec.Cell, precision: str | None = None) -> list:
             "--lr", repr(t["lr"]), "--warmup", str(t["warmup"]),
             "--steps", str(t["steps"]), "--b2", repr(t["b2"]),
             "--weight-decay", repr(t["weight_decay"])] + list(t["launcher"])
+    argv += list(c.get("launcher", []))
     if t["dp"] > 1:
         argv += ["--dp", str(t["dp"]), "--zero"]
     if precision is not None:
@@ -102,11 +78,19 @@ class TrainProgram:
         from repro.train import sharded
 
         self.cell = cell
+        self.family = cell.family
         self.args = train.parse_args(program_argv(cell, precision))
         (self.cfg, self.model, self.opt, self.step_fn, _, self.mesh,
          _) = train.build(self.args)
-        self.dims = dense_gqa.dims_of(cell.config)
-        self._check_widths()
+        self.dims = self.family.dims_of(cell.config)
+        self.table = self.family.shapes(self.dims)
+        differ = self.family.check_widths(self.cfg, self.opt, self.dims,
+                                          cell.traffic)
+        if differ:
+            raise spec.SpecError(
+                f"program config {self.cfg.name} differs from the "
+                f"configuration and traffic files: {differ} (program's, "
+                f"files')")
         if not self.opt.policy.bucketing.enabled:
             raise spec.SpecError("the harness reads the bucketed state: "
                                  "the traffic must pass --bucketed")
@@ -126,21 +110,11 @@ class TrainProgram:
                                   out_shardings=state_shardings)
         self.compiled = None
 
-    def _check_widths(self):
-        c, d, t = self.cfg, self.dims, self.cell.traffic
-        got = (c.d_model, c.n_heads, c.n_kv_heads, c.head_dim_, c.d_ff,
-               c.vocab_size, c.n_layers, c.tie_embeddings, c.rope_theta,
-               c.norm_eps, self.opt.b1, self.opt.eps)
-        want = (d.d, d.heads, d.kv_heads, d.head_dim, d.ff, d.vocab,
-                d.layers, d.tied, d.rope_theta, d.eps, t["b1"], t["eps"])
-        if got != want:
-            raise spec.SpecError(f"program config {c.name} {got} is not the "
-                                 f"configuration and traffic files' {want}")
-
     def _make_state(self, key):
         """The program's train state, its weights made from ``key``."""
         from repro.train import train_loop
-        params = to_program(weights.generate(key, self.dims, BF16))
+        params = self.family.to_program(weights.generate(key, self.table,
+                                                         BF16))
         want = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
         if (jax.tree_util.tree_structure(params)
                 != jax.tree_util.tree_structure(want)
@@ -177,7 +151,8 @@ class TrainProgram:
         one: m₁ = (1 − β₁)·g, the factor in m's storage type."""
         @jax.jit
         def norms(state):
-            m = from_program(self._tree(state.opt_state.m, state))
+            m = self.family.from_program(self._tree(state.opt_state.m,
+                                                    state))
             out = {}
             for k, v in m.items():
                 c = jnp.asarray(1.0 - self.opt.b1, v.dtype).astype(jnp.float32)
@@ -209,15 +184,10 @@ class TrainProgram:
         memory read after the window stays the step's own."""
         @functools.partial(jax.jit, static_argnums=2)
         def norm(state, key, name):
-            w = from_program(self.value(state))[name]
-            w0 = weights.tensor(key, self.dims, name, BF16)
+            w = self.family.from_program(self.value(state))[name]
+            w0 = weights.tensor(key, self.table, name, BF16)
             return jnp.sqrt(jnp.sum(jnp.square(w - w0.astype(jnp.float32))))
-        return {k: float(norm(state, key_w, k))
-                for k in sorted(weights.shapes(self.dims))}
-
-    def kernel_ops(self, names) -> dict:
-        """{instruction: kernel} of the compiled step's Mosaic kernels."""
-        return trace_lib.kernel_ops(self.compiled.as_text(), names)
+        return {k: float(norm(state, key_w, k)) for k in sorted(self.table)}
 
 
 def peak_bytes(stats: dict) -> int:
@@ -286,18 +256,20 @@ def run_window(prog: TrainProgram, state, pool, seconds: float):
 
 
 def reference_readings(cell: spec.Cell, key_w, check_tokens, devices) -> dict:
-    """The plain reference over the set-up steps' batches."""
-    dm = dense_gqa.dims_of(cell.config)
-    t = cell.traffic
-    opt = dense_gqa.AdamW(lr=t["lr"], warmup=t["warmup"], total=t["steps"],
-                          b1=t["b1"], b2=t["b2"], eps=t["eps"],
-                          weight_decay=t["weight_decay"])
+    """The plain reference of the cell's family over the set-up steps'
+    batches."""
+    fam, t = cell.family, cell.traffic
+    dm = fam.dims_of(cell.config)
+    opt = adamw.AdamW(lr=t["lr"], warmup=t["warmup"], total=t["steps"],
+                      b1=t["b1"], b2=t["b2"], eps=t["eps"],
+                      weight_decay=t["weight_decay"])
     mesh = jax.sharding.Mesh(devices, ("rows",))
     rows = NamedSharding(mesh, P("rows", None))
     rep = NamedSharding(mesh, P())
     toks = [jax.device_put(x, rows) for x in check_tokens]
-    w0 = lambda: jax.device_put(weights.generate(key_w, dm, BF16), rep)
-    return dense_gqa.run(w0, toks, opt, dm, mesh)
+    w0 = lambda: jax.device_put(weights.generate(key_w, fam.shapes(dm), BF16),
+                                rep)
+    return fam.run(w0, toks, opt, dm, mesh)
 
 
 def keys(seed: int):
@@ -364,8 +336,12 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     losses = [float(m["loss"]) for m in win.metrics]
     last = {k: float(v) for k, v in win.metrics[-1].items()}
     window_s, ends, opt_bytes = win.seconds, win.ends, optimizer_bytes(state)
-    kernel_ops = prog.kernel_ops(t["kernels"])
+    text = prog.compiled.as_text()
+    kernel_ops = trace_lib.kernel_ops(text, t["kernels"])
     missing = len(set(t["kernels"]) - set(kernel_ops.values()))
+    phase_map = scopes.phase_map(text) if trace else None
+    scope_paths = scopes.scope_paths(text) if trace else None
+    del text
     devices = prog.devices
     check_tokens = tokens[:CHECK_STEPS]
     dims = prog.dims
@@ -385,5 +361,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             "readings": {"program": prog_read, "reference": ref,
                          "values": values},
             "events": events, "kernel_ops": kernel_ops,
-            "optimizer_bytes": opt_bytes, "dims": dims, "last_step": last,
+            "phase_map": phase_map, "scope_paths": scope_paths,
+            "optimizer_bytes": opt_bytes, "family": cell.family,
+            "dims": dims, "last_step": last,
             "memory": memory, "step_ends": ends}

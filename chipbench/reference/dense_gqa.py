@@ -1,5 +1,6 @@
-"""Plain reference for the dense GQA decoder: loss, gradients and AdamW in
-float32 ``jax.numpy`` under ``default_matmul_precision("highest")``.
+"""Plain reference for the dense GQA decoder: loss and gradients in float32
+``jax.numpy`` under ``default_matmul_precision("highest")``, stepped by the
+one AdamW reference of every family (``reference/adamw.py``).
 
 The block is the published Llama-style decoder that granite-3.0 and
 InternLM2 share: pre-RMSNorm, rotary embedding on half-split pairs,
@@ -16,19 +17,21 @@ the cell has several chips, each takes its share of the rows and the sums
 are added. None of that changes the arithmetic beyond float32 summation
 order.
 
-Weights are a dict of arrays named as ``chipbench/weights.py`` makes them:
+Weights are a dict of arrays named as the dense family's table
+(``chipbench/families/dense_gqa.py``) names them:
 ``embed`` (V, d), per-layer tensors stacked on a leading layer axis
 (``attn_norm``, ``wq``, ``wk``, ``wv``, ``wo``, ``mlp_norm``, ``w_gate``,
 ``w_up``, ``w_down``), ``final_norm`` and, untied, ``lm_head`` (d, V)."""
 from __future__ import annotations
 
 import functools
-import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+
+from chipbench.reference import adamw
 
 F32 = jnp.float32
 Q_BLOCK = 512          # queries per attention block
@@ -190,90 +193,20 @@ def loss_and_grad(w, tokens, dm: Dims, mesh: Mesh):
     return tot / n, jax.tree_util.tree_map(lambda g: g / n, grad)
 
 
-class AdamW(NamedTuple):
-    """Decoupled weight decay inside the update; linear warm-up, then
-    cosine decay to ``min_ratio`` of the peak rate."""
-    lr: float
-    warmup: int
-    total: int
-    b1: float
-    b2: float
-    eps: float
-    weight_decay: float
-    min_ratio: float = 0.1
-
-    def rate(self, t: int) -> float:
-        if t < self.warmup:
-            return self.lr * t / max(self.warmup, 1)
-        prog = min(max((t - self.warmup) / max(self.total - self.warmup, 1),
-                       0.0), 1.0)
-        return self.lr * (self.min_ratio + (1 - self.min_ratio) * 0.5
-                          * (1 + math.cos(math.pi * prog)))
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _apply(theta, grads, lr, bc1, bc2, hp):
-    """θ after one AdamW step whose moments come from ``grads``: a list of
-    the steps' gradients, oldest first (the moments start at zero)."""
-    b1, b2, eps, wd = hp
-
-    def leaf(th, *gs):
-        m = jnp.zeros_like(th)
-        v = jnp.zeros_like(th)
-        for g in gs:
-            m = b1 * m + (1 - b1) * g
-            v = b2 * v + (1 - b2) * g * g
-        upd = (m / bc1) / (jnp.sqrt(v / bc2) + eps) + wd * th
-        return th - lr * upd
-
-    return jax.tree_util.tree_map(leaf, theta, *grads)
-
-
-def adamw_step(theta, grads: list, t: int, opt: AdamW):
-    """Step ``t`` (1-based) of AdamW given every gradient so far."""
-    hp = (opt.b1, opt.b2, opt.eps, opt.weight_decay)
-    return _apply(theta, grads, jnp.float32(opt.rate(t)),
-                  jnp.float32(1 - opt.b1 ** t), jnp.float32(1 - opt.b2 ** t),
-                  hp)
-
-
-def leaf_norms(tree) -> dict:
-    """float32 L2 norm of every named tensor."""
-    return {k: float(jnp.sqrt(jnp.sum(jnp.square(v.astype(F32)))))
-            for k, v in tree.items()}
-
-
-@jax.jit
-def _diff_norms(a, b):
-    return {k: jnp.sqrt(jnp.sum(jnp.square(a[k].astype(F32)
-                                           - b[k].astype(F32))))
-            for k in a}
-
-
-def change_norms(theta, theta0) -> dict:
-    """Per tensor, the L2 norm of θ − θ₀."""
-    return {k: float(v) for k, v in _diff_norms(theta, theta0).items()}
-
-
-@jax.jit
-def _to_f32(w):
-    return {k: v.astype(F32) for k, v in w.items()}
-
-
-def run(make_w0, batches, opt: AdamW, dm: Dims, mesh: Mesh) -> dict:
+def run(make_w0, batches, opt: adamw.AdamW, dm: Dims, mesh: Mesh) -> dict:
     """Two AdamW steps from the weights ``make_w0()`` on ``batches[0]`` and
     ``batches[1]`` and the loss on ``batches[2]``: the losses of the three
     steps, the first gradient's norm per tensor and the change after two
     steps. ``make_w0`` makes the weights anew when they are needed, so
     that they are not held while the gradients are."""
-    theta = _to_f32(make_w0())
+    theta = adamw.to_f32(make_w0())
     l1, g1 = loss_and_grad(theta, batches[0], dm, mesh)
-    grad_norms = leaf_norms(g1)
-    theta = adamw_step(theta, [g1], 1, opt)
+    grad_norms = adamw.leaf_norms(g1)
+    theta = adamw.step(theta, [g1], 1, opt)
     l2, g2 = loss_and_grad(theta, batches[1], dm, mesh)
-    theta = adamw_step(theta, [g1, g2], 2, opt)
+    theta = adamw.step(theta, [g1, g2], 2, opt)
     del g1, g2
     l3 = loss(theta, batches[2], dm, mesh)
     return {"losses": [float(l1), float(l2), float(l3)],
             "grad_norms": grad_norms,
-            "change_norms": change_norms(theta, make_w0())}
+            "change_norms": adamw.change_norms(theta, make_w0())}

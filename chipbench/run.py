@@ -32,7 +32,8 @@ CACHE_DIR = ROOT / ".jax_cache"     # fixed: the path is part of the key
 @dataclasses.dataclass
 class Facts:
     """What a per-layer metric reader (``chipbench/metrics``) reads."""
-    dims: object             # reference.dense_gqa.Dims
+    family: object           # the cell's module chipbench/families/<family>
+    dims: object             # the family's sizes (``family.dims_of``)
     traffic: dict
     chips: int
     peaks: dict
@@ -41,6 +42,16 @@ class Facts:
     tokens_per_step: int     # all chips
     optimizer_bytes: int     # collage_update bytes per step, one chip
     summary: object          # trace.Summary
+    phases: dict | None      # scopes.phase_seconds; None without scopes
+    events: dict             # trace.load of the traced window
+    scope_paths: dict        # scopes.scope_paths of the compiled step
+
+    def scope_s(self, name: str):
+        """Device self time in the window, seconds per chip, of the ops
+        under the named scope ``name`` (any component of their op_name
+        path); None where the program names no such scope."""
+        from chipbench import scopes
+        return scopes.scope_seconds(self.events, self.scope_paths, name)
 
 
 def fail(msg: str) -> int:
@@ -73,7 +84,7 @@ def per_layer(cell, facts: Facts) -> dict:
 
 
 def result_line(cell, res: dict, trace: bool, device) -> dict:
-    from chipbench import spec, trace as trace_lib
+    from chipbench import scopes, spec, trace as trace_lib
     devs = res["devices"]
     dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
            "count": len(devs), "memory_peak_bytes": res["peak_bytes"]}
@@ -81,17 +92,23 @@ def result_line(cell, res: dict, trace: bool, device) -> dict:
             "failed": res["failed"]}
     if trace:
         summary = trace_lib.summarize(res["events"], res["kernel_ops"])
-        facts = Facts(dims=res["dims"], traffic=cell.traffic,
-                      chips=len(devs), peaks=spec.peaks(device.device_kind),
+        facts = Facts(family=res["family"], dims=res["dims"],
+                      traffic=cell.traffic, chips=len(devs),
+                      peaks=spec.peaks(device.device_kind),
                       steps=res["attempted"], window_s=res["window_s"],
                       tokens_per_step=res["tokens_per_step"],
                       optimizer_bytes=res["optimizer_bytes"],
-                      summary=summary)
+                      summary=summary,
+                      phases=scopes.phase_seconds(res["events"],
+                                                  res["phase_map"]),
+                      events=res["events"],
+                      scope_paths=res["scope_paths"])
         line["metrics"] = per_layer(cell, facts)
         dev.update(busy_s=summary.busy_s, window_s=summary.window_s)
         line["device"] = dev
         line["breakdown"] = {"device_ops": summary.device_ops,
                              "idle_gaps": summary.idle_gaps}
+        line["phases"] = facts.phases
     else:
         values = {
             "train_tokens_per_s": res["tokens_per_step"] * res["attempted"]

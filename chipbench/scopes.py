@@ -12,12 +12,13 @@ program's text.
 
 ``phase_map`` reads that text; ``phase_seconds`` adds up device self time
 per phase inside the measured window. A program without these scopes (an
-older commit) gives an empty map and no phase times.
+older commit) gives an empty map and no phase times. ``scope_paths`` and
+``scope_seconds`` do the same for any one scope, named or not in
+``SCOPES``: the self time of the ops with that name on their path.
 
-No per-layer metric reads the split yet: ``harness.run`` does not keep the
-compiled text of a traced run, nor ``run.Facts`` the phase times. Given
-``events`` (``trace.load``) and the step's ``compiled.as_text()``, call
-``phase_seconds(events, phase_map(text))``."""
+``harness.run`` keeps both maps of a traced run; ``run.Facts`` gives the
+phase times (``phases``) and ``scope_s(name)`` to the per-layer readers
+(``metrics/*_share.py``)."""
 from __future__ import annotations
 
 import re
@@ -199,14 +200,20 @@ def instructions(hlo_text: str) -> dict:
     return out
 
 
+def _phase_names(ins: Instruction) -> list:
+    """Those of an op's ``;``-joined names (a fused op's) that name a
+    phase, in order."""
+    return [n for n in ins.names if phase_of(n) != "unattributed"]
+
+
 def phase_map(hlo_text: str) -> dict:
     """{instruction: Scope} of the compiled program's text, empty where the
-    program opens none of the step's scopes. Of an op's ``;``-joined names
-    (a fused op's) those that name a phase decide: where they disagree the
-    op takes the first one's phase and is marked ``mixed``."""
+    program opens none of the step's scopes. Of an op's names those that
+    name a phase decide: where they disagree the op takes the first one's
+    phase and is marked ``mixed``."""
     out = {}
     for name, ins in instructions(hlo_text).items():
-        named = [n for n in ins.names if phase_of(n) != "unattributed"]
+        named = _phase_names(ins)
         if not named:
             out[name] = Scope("unattributed", "-", False, False)
             continue
@@ -217,15 +224,21 @@ def phase_map(hlo_text: str) -> dict:
     return out if any(sc.leaf != "-" for sc in out.values()) else {}
 
 
-def phase_seconds(events: dict, pmap: dict, top: int = 10):
-    """Device self time per phase inside the ``chipbench.window`` span, in
-    seconds per chip (mean over chips): {"phases": {phase: s}, "head": s,
-    "mixed": s, "scopes": [[phase/leaf, s], ...] (the ``top`` largest)};
-    None for an empty map. An op the map does not know is
-    ``unattributed``. Self time is an op's time less that of the ops
-    nested in it, so a loop counts once."""
-    if not pmap:
-        return None
+def scope_paths(hlo_text: str) -> dict:
+    """{instruction: frozenset of the names on its path}, transformations
+    peeled (``_components``), of the name ``phase_map`` reads: the first of
+    an op's names that names a phase, else its first."""
+    out = {}
+    for name, ins in instructions(hlo_text).items():
+        named = _phase_names(ins) or list(ins.names)
+        out[name] = frozenset(_components(named[0])) if named else frozenset()
+    return out
+
+
+def _window_self_times(events: dict):
+    """(per chip, [(op, self ns)] of the ops in the ``chipbench.window``
+    span, clipped to it; the number of chips). Self time is an op's time
+    less that of the ops nested in it, so a loop counts once."""
     win = [h for h in events["host"] if h[0] == trace.HOST_PREFIX + "window"]
     if len(win) != 1:
         raise RuntimeError(f"expected one window span, found {len(win)}")
@@ -233,23 +246,50 @@ def phase_seconds(events: dict, pmap: dict, top: int = 10):
     devs = sorted(events["devices"])
     if not devs:
         raise RuntimeError("no device ran an operation in the window")
-    phases = dict.fromkeys(PHASES, 0.0)
-    by_scope: dict = {}
-    head = mixed = 0.0
-    none = Scope("unattributed", "-", False, False)
+    per_chip = []
     for d in devs:
         ops = [[name, max(s, w0), min(s + t, w1) - max(s, w0)]
                for name, s, t in events["devices"][d]
                if s + t > w0 and s < w1]
         self_t, _ = trace.nesting(ops)
-        for (name, _, _), st in zip(ops, self_t):
+        per_chip.append([(op[0], st) for op, st in zip(ops, self_t)])
+    return per_chip, len(devs)
+
+
+def scope_seconds(events: dict, paths: dict, scope: str):
+    """Device self time in the window, seconds per chip (mean over chips),
+    of the ops with ``scope`` on their path (``scope_paths``); None where
+    no instruction of the program has it."""
+    under = {name for name, p in paths.items() if scope in p}
+    if not under:
+        return None
+    per_chip, n = _window_self_times(events)
+    return sum(st for ops in per_chip for name, st in ops
+               if name in under) / (n * 1e9)
+
+
+def phase_seconds(events: dict, pmap: dict, top: int = 10):
+    """Device self time per phase inside the ``chipbench.window`` span, in
+    seconds per chip (mean over chips): {"phases": {phase: s}, "head": s,
+    "mixed": s, "scopes": [[phase/leaf, s], ...] (the ``top`` largest)};
+    None for an empty map. An op the map does not know is
+    ``unattributed``."""
+    if not pmap:
+        return None
+    per_chip, n_chips = _window_self_times(events)
+    phases = dict.fromkeys(PHASES, 0.0)
+    by_scope: dict = {}
+    head = mixed = 0.0
+    none = Scope("unattributed", "-", False, False)
+    for ops in per_chip:
+        for name, st in ops:
             sc = pmap.get(name, none)
             phases[sc.phase] += st
             key = f"{sc.phase}/{sc.leaf}"
             by_scope[key] = by_scope.get(key, 0.0) + st
             head += st if sc.head else 0.0
             mixed += st if sc.mixed else 0.0
-    n = len(devs) * 1e9
+    n = n_chips * 1e9
     ranked = sorted(by_scope.items(), key=lambda kv: -kv[1])[:top]
     return {"phases": {k: v / n for k, v in phases.items()},
             "head": head / n, "mixed": mixed / n,

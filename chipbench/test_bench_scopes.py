@@ -3,17 +3,18 @@
 On a trace recorded on a TPU v5 lite (``fixtures/trace_granite_2l_scopes
 .json.gz``: granite-3-2b at 2 layers, 1 × 4096, three steps under
 ``--remat full``, with the compiled program's text) the phases add up to
-the device's busy time and each Mosaic kernel falls in the phase it
-belongs to; the precedence of the phases, fused ops whose names disagree,
-and the ops the compiler adds without a name, on small hand-made
-programs."""
+the device's busy time, each Mosaic kernel falls in the phase it belongs
+to, the phase readers of ``chipbench/metrics`` add up to the busy share,
+and a scope's time read by name agrees with the phases'; the precedence
+of the phases, fused ops whose names disagree, and the ops the compiler
+adds without a name, on small hand-made programs."""
 import gzip
 import json
 import pathlib
 
 import pytest
 
-from chipbench import scopes, trace
+from chipbench import run, scopes, spec, trace
 
 FIXTURE = (pathlib.Path(__file__).parent / "fixtures"
            / "trace_granite_2l_scopes.json.gz")
@@ -38,6 +39,55 @@ def test_phases_add_up_to_the_busy_time(recorded):
     assert 0 < got["head"] < named
     assert len(got["scopes"]) == 10
     assert got["scopes"] == sorted(got["scopes"], key=lambda kv: -kv[1])
+
+
+PHASE_READERS = ("forward_share", "recompute_share", "backward_share",
+                 "optimizer_share", "bucket_views_share")
+
+
+def facts(events, hlo):
+    return run.Facts(
+        family=None, dims=None, traffic={}, chips=1, peaks={}, steps=3,
+        window_s=0.0, tokens_per_step=0, optimizer_bytes=0,
+        summary=trace.summarize(events, trace.kernel_ops(hlo, KERNELS)),
+        phases=scopes.phase_seconds(events, scopes.phase_map(hlo)),
+        events=events, scope_paths=scopes.scope_paths(hlo))
+
+
+def test_phase_readers_add_up_to_the_busy_share(recorded):
+    f = facts(*recorded)
+    shares = {n: spec.metric_reader(n)(f) for n in PHASE_READERS}
+    window = f.summary.window_s
+    unattributed = 100 * f.phases["phases"]["unattributed"] / window
+    busy = 100 * f.summary.busy_s / window
+    assert sum(shares.values()) + unattributed == pytest.approx(busy,
+                                                                rel=0.01)
+    assert all(v > 0 for v in shares.values()), shares
+    head = spec.metric_reader("lm_head_share")(f)
+    assert 0 < head < busy
+    # the head's time lies in the phases, not beside them
+    assert head == pytest.approx(100 * f.phases["head"] / window)
+
+
+def test_readers_read_nothing_without_scopes(recorded):
+    events, hlo = recorded
+    f = facts(events, hlo)
+    f.phases = None
+    for n in PHASE_READERS + ("lm_head_share", "grad_exchange_share"):
+        assert spec.metric_reader(n)(f) is None, n
+
+
+def test_a_scope_read_by_name_is_the_phases_entries(recorded):
+    events, hlo = recorded
+    f = facts(events, hlo)
+    ranked = dict(scopes.phase_seconds(events, scopes.phase_map(hlo),
+                                       top=1000)["scopes"])
+    mlp = [ranked[f"{p}/mlp"] for p in ("forward", "recompute", "backward")]
+    assert all(v > 0 for v in mlp)
+    assert f.scope_s("mlp") == pytest.approx(sum(mlp), rel=1e-12)
+    # a scope on the path above the leaf: every phase of the model
+    assert f.scope_s("forward") > f.scope_s("mlp") + f.scope_s("attention")
+    assert f.scope_s("no_such_scope") is None
 
 
 def test_kernels_fall_in_their_phase(recorded):

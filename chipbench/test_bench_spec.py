@@ -54,6 +54,8 @@ def test_config_entry(entry):
     assert any(entry["file"].startswith(p + "/") for p in BENCH["paths"])
     config = json.loads((spec.ROOT / entry["file"]).read_text())
     assert config["name"] == entry["name"]
+    family = spec.load_family(config["family"])
+    assert family.dims_of(config).vocab == config["vocab_size"]
     assert config["source"] == entry["source"]
     assert entry["source"].startswith("https://")
     assert len(entry["reduced"]) <= 16

@@ -1,16 +1,16 @@
-"""Weights of a dense GQA decoder, made on the device from the seed.
+"""Weights of a model, made on the device from the seed.
 
-One jitted call makes every tensor, in the type it is trained in (the
-configuration's ``torch_dtype``), so the program under test and the plain
-reference start from the same values: the reference widens them to
-float32 exactly. The rounding to that type is explicit: inside a larger
+A family's table (``shapes(dims)`` of ``chipbench/families/<family>.py``:
+name → (shape, standard deviation)) says what to make. One jitted call
+makes every tensor, in the type it is trained in (the configuration's
+``torch_dtype``), so the program under test and the plain reference start
+from the same values: the reference widens them to float32 exactly. Each
+tensor is normal with its standard deviation, from its own fold of the
+key: its index among the sorted names; a standard deviation of 0 makes
+zeros. The rounding to the trained type is explicit: inside a larger
 jitted computation that widens the tensors again, XLA may drop a plain
-narrowing conversion (its excess precision), and the values would then not
-be the trained ones. Names and layouts are those of
-``chipbench/reference/dense_gqa.py``; per-layer tensors are stacked on a
-leading layer axis. Matrices are normal with standard deviation
-fan_in^-1/2, the embedding and the untied head 0.02, and the RMSNorm
-offsets zero."""
+narrowing conversion (its excess precision), and the values would then
+not be the trained ones."""
 from __future__ import annotations
 
 import functools
@@ -19,42 +19,21 @@ import math
 import jax
 import jax.numpy as jnp
 
-from chipbench.reference.dense_gqa import Dims
 
-
-def shapes(dm: Dims) -> dict:
-    """name → (shape, standard deviation; 0 for the zero-initialised)."""
-    d, h, hk, dh, f, V, n = (dm.d, dm.heads, dm.kv_heads, dm.head_dim,
-                             dm.ff, dm.vocab, dm.layers)
-    out = {
-        "embed": ((V, d), 0.02),
-        "attn_norm": ((n, d), 0.0),
-        "wq": ((n, d, h * dh), d ** -0.5),
-        "wk": ((n, d, hk * dh), d ** -0.5),
-        "wv": ((n, d, hk * dh), d ** -0.5),
-        "wo": ((n, h * dh, d), (h * dh) ** -0.5),
-        "mlp_norm": ((n, d), 0.0),
-        "w_gate": ((n, d, f), d ** -0.5),
-        "w_up": ((n, d, f), d ** -0.5),
-        "w_down": ((n, f, d), f ** -0.5),
-        "final_norm": ((d,), 0.0),
-    }
-    if not dm.tied:
-        out["lm_head"] = ((d, V), 0.02)
-    return out
+def generate(key, table: dict, dtype) -> dict:
+    """Every tensor of ``table``."""
+    return _generate(key, tuple(sorted(table.items())), dtype)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
-def generate(key, dm: Dims, dtype) -> dict:
-    """Every tensor of ``shapes(dm)``."""
-    return {name: tensor(key, dm, name, dtype) for name in shapes(dm)}
+def _generate(key, items: tuple, dtype) -> dict:
+    table = dict(items)
+    return {name: tensor(key, table, name, dtype) for name in table}
 
 
-def tensor(key, dm: Dims, name: str, dtype):
-    """One tensor of ``shapes(dm)``, from its own fold of ``key``: its
-    index among the sorted names. Made inside another computation, it
-    takes the same values as in ``generate``."""
-    table = shapes(dm)
+def tensor(key, table: dict, name: str, dtype):
+    """One tensor of ``table``. Made inside another computation, it takes
+    the same values as in ``generate``."""
     shape, std = table[name]
     if std == 0.0:
         return jnp.zeros(shape, dtype)
@@ -65,5 +44,5 @@ def tensor(key, dm: Dims, name: str, dtype):
         x, exponent_bits=fi.nexp, mantissa_bits=fi.nmant).astype(dtype)
 
 
-def n_params(dm: Dims) -> int:
-    return sum(math.prod(shape) for shape, _ in shapes(dm).values())
+def n_params(table: dict) -> int:
+    return sum(math.prod(shape) for shape, _ in table.values())
